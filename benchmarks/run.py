@@ -18,6 +18,7 @@ import sys
 import traceback
 
 from benchmarks import common
+from repro.backends import use_compile_cache
 
 # Modules with a recorded perf trajectory: their rows emit to these
 # baseline files under --emit-dir (committed copies live at repo root).
@@ -53,6 +54,7 @@ def main() -> None:
                     help="write BENCH_*.json baselines for the gated "
                          "modules into DIR")
     args = ap.parse_args()
+    use_compile_cache()
     if args.emit_dir:
         os.makedirs(args.emit_dir, exist_ok=True)
     print("name,us_per_call,derived")

@@ -254,9 +254,28 @@ def _build_sharded_words_pipeline(program, interpret: bool = False,
 
 def on_tpu() -> bool:
     """The one TPU-detection rule: gates Pallas auto-selection here and
-    the interpret-mode fallback in kernels/{ops,fused_program}.py."""
+    the Pallas dispatch of the kernel wrappers in kernels/ops.py."""
     import jax
     return jax.default_backend() == "tpu"
+
+
+def use_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed directory and
+    return it. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has
+    already read it and nothing changes. Otherwise the cache goes to
+    ``.jax_cache`` at the root of this checkout: the directory is part of
+    what a cached program is found by, so it never depends on a temp
+    directory, a pid or the time. Called by entry points (the chip smoke
+    run, the benchmark CLI), never on import."""
+    import os
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        root = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        path = os.path.join(root, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def multi_device() -> bool:

@@ -280,6 +280,15 @@ def _DEAD_REF():  # weakref stand-in for ops that must never be outputs
     return None
 
 
+def _split_devices(out) -> int:
+    """Devices a pipeline output is split across: 0 for a host NumPy
+    array, 1 for a single-device or replicated device array."""
+    sharding = getattr(out, "sharding", None)
+    if sharding is None:
+        return 0
+    return 1 if sharding.is_fully_replicated else len(sharding.device_set)
+
+
 def _stage_wire(flat, pad: int, layout: PlaneLayout,
                 copy: bool = False) -> np.ndarray:
     """Flat lane array -> padded int32 wire array with AT MOST one host
@@ -1145,11 +1154,15 @@ class PulsarEngine:
         return out
 
     def _graph_over_threshold(self, g: _OpGraph) -> str | None:
-        """Auto-flush policy: graph-size (recorded ops) and estimated
+        """Auto-flush policy: graph-size (recorded ops), estimated
         memory (one layout word per lane per held value: leaf snapshots
-        plus the pipeline's per-op intermediates). Returns the trigger
-        name ("ops"/"memory", doubling as the telemetry counter suffix)
-        or None when the graph may keep growing."""
+        plus the pipeline's per-op intermediates), and the Pallas
+        kernel's VMEM block (every plane of one tile of every leaf and
+        output; ops bound the outputs). The VMEM bound is always on, so
+        no flush can build a program the TPU kernel cannot compile.
+        Returns the trigger name ("ops"/"memory"/"vmem", doubling as the
+        telemetry counter suffix) or None when the graph may keep
+        growing."""
         if self.flush_threshold is not None \
                 and len(g.ops) >= self.flush_threshold:
             return "ops"
@@ -1158,6 +1171,9 @@ class PulsarEngine:
                 * (len(g.leaves) + len(g.ops))
             if est >= self.flush_memory_bytes:
                 return "memory"
+        if _fused.block_bytes(len(g.leaves) + len(g.ops), g.width) \
+                >= _fused.VMEM_BLOCK_BUDGET:
+            return "vmem"
         return None
 
     def flush(self) -> None:
@@ -1496,6 +1512,8 @@ class PulsarEngine:
             self.counters.inc("engine.flushes")
             self.counters.observe("engine.flush_lanes", g.n)
             self.counters.observe("engine.flush_ops", len(program.ops))
+            self.counters.observe("engine.flush_devices",
+                                  _split_devices(outs[0]))
         if self.autotuner is not None:
             # Per-flush decision point: the online autotuner counts
             # windows / takes counter deltas here (reentrancy-guarded on

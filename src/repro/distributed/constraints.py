@@ -13,11 +13,8 @@ from jax.sharding import PartitionSpec as P
 
 
 def _current_axes() -> tuple[str, ...]:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # noqa: BLE001 — older API fallback
-        return ()
-    if mesh is None or getattr(mesh, "empty", True):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return ()
     return tuple(mesh.axis_names)
 
@@ -32,11 +29,8 @@ def gather_layer_params(cfg, lp):
     axes = _current_axes()
     if "model" not in axes:
         return lp
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        msize = dict(zip(mesh.axis_names, mesh.axis_sizes))["model"]
-    except Exception:  # noqa: BLE001
-        return lp
+    mesh = jax.sharding.get_abstract_mesh()
+    msize = dict(zip(mesh.axis_names, mesh.axis_sizes))["model"]
 
     def one(path, leaf):
         parts = [str(getattr(p, "key", getattr(p, "idx", p))) for p in path]

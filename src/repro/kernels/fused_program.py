@@ -36,10 +36,10 @@ other (tests/kernels):
     global x64 flag. divmod is Knuth Algorithm D over base-2^16 digits
     (one hardware uint32 division per quotient digit).
 
-Word-domain pipelines short-circuit per call to the NumPy evaluator when
-the program is tiny (``_NP_CUTOFF_WIRE_OPS`` wire-words x ops): for a
-2-op bitmap AND over a handful of lanes, one XLA dispatch costs more
-than the whole program.
+On a CPU host, word-domain pipelines short-circuit per call to the NumPy
+evaluator when the program is tiny (``_NP_CUTOFF_WIRE_OPS`` wire-words x
+ops): for a 2-op bitmap AND over a handful of lanes, one XLA dispatch
+costs more than the whole program. On an accelerator they never do.
 
 Programs are frozen/hashable, so compiled pipelines are cached on graph
 *structure*: re-recording the same op sequence over new batches reuses the
@@ -95,8 +95,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.backends import get_backend, on_tpu as _on_tpu, select_backend
+from repro.backends import get_backend, select_backend
 from repro.kernels import ref
 from repro.kernels.bit_transpose import bit_transpose32 as _pl_transpose
 from repro.kernels.plane_layout import LAYOUT32, PlaneLayout
@@ -642,6 +643,25 @@ def run_program_pairs(program: FusedProgram, leaves: list) -> tuple:
 # --------------------------------------------------------------------- #
 
 
+# VMEM of the Pallas program kernel. Its block holds every plane of every
+# leaf and every output for one (8, 128) int32 tile, and the pipeline
+# double-buffers it, so a block grows with values x width; Mosaic's default
+# scoped limit (16 MiB on v5e) refuses one of 64 leaves x 32 planes. The
+# kernel asks for what its block needs plus that default as headroom (the
+# program's intermediates do not count against the limit), and the
+# engine's auto-flush keeps every block within VMEM_BLOCK_BUDGET, so the
+# request stays under v5e's 128 MiB of physical VMEM.
+TILE_BYTES = BLOCK_WORDS * 4
+VMEM_BLOCK_BUDGET = 64 << 20
+_VMEM_HEADROOM = 16 << 20
+
+
+def block_bytes(n_values: int, width: int) -> int:
+    """Double-buffered VMEM block of ``n_values`` values of ``width``
+    planes in the Pallas program kernel."""
+    return 2 * n_values * width * TILE_BYTES
+
+
 def _program_kernel(x_ref, o_ref, *, program: FusedProgram):
     env = [[x_ref[i, j] for j in range(program.width)]
            for i in range(program.n_inputs)]
@@ -672,6 +692,9 @@ def run_program_pallas(program: FusedProgram, x: jax.Array,
         out_shape=jax.ShapeDtypeStruct((n_out, width, blocks, SUBLANE, LANE),
                                        jnp.int32),
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=block_bytes(n_in + n_out, width)
+            + _VMEM_HEADROOM),
     )(xb)
     return out.reshape(n_out, width, blocks * BLOCK_WORDS)[:, :, :w] \
         .astype(x.dtype)
@@ -767,10 +790,10 @@ def _donating(fn, n_leaves: int):
     return call
 
 
-# Per-call NumPy short-circuit threshold for word pipelines, in
-# wire-words x ops: below it, XLA dispatch overhead (which grows with
-# the leaf count — each argument is canonicalized and placed) costs more
-# than evaluating the whole program in NumPy with last-use buffer
+# Per-call NumPy short-circuit threshold for word pipelines on a CPU
+# host, in wire-words x ops: below it, XLA dispatch overhead (which grows
+# with the leaf count — each argument is canonicalized and placed) costs
+# more than evaluating the whole program in NumPy with last-use buffer
 # recycling (a k-clique AND pair over a few lanes is ~100 wire-ops and
 # stays NumPy; the paper-scale 30-leaf BMI scan is ~10^7 wire-ops and
 # the 2M-lane prog16 staple is ~10^7 — both win jitted, where XLA's
@@ -780,22 +803,25 @@ _NP_CUTOFF_WIRE_OPS = 1 << 20
 
 
 def build_words_pipeline(program: FusedProgram, donate: bool = False):
-    """Word-domain pipeline (the CPU execution path): the bracketing
-    transpose pair cancels algebraically, so the program fuses directly
-    on horizontal words — one jax.jit trace at EVERY layout. 32-bit
-    lanes evaluate on uint32 words; 64-bit lanes evaluate as uint32
-    (lo, hi) pairs (``run_program_pairs``, carry chained across the pair
-    in the IR), so the wide path no longer drops to un-jitted NumPy and
-    ``donate`` works at both layouts. Tiny programs short-circuit per
-    call to the NumPy word evaluator (``_NP_CUTOFF_WIRE_OPS``), and so
-    do 64-bit programs containing division: x86 has no SIMD integer
-    divide, so the pair evaluator's Knuth long division scalarizes the
-    fused XLA loop (~100 elementwise passes per divmod), while NumPy's
-    hardware 64-bit ``divq`` is one pass — with copy-on-write staging
-    the host path wins at every size."""
+    """Word-domain pipeline: the bracketing transpose pair cancels
+    algebraically, so the program fuses directly on horizontal words —
+    one jax.jit trace at EVERY layout, on whatever device JAX runs on.
+    32-bit lanes evaluate on uint32 words; 64-bit lanes evaluate as
+    uint32 (lo, hi) pairs (``run_program_pairs``, carry chained across
+    the pair in the IR), so ``donate`` works at both layouts.
+
+    Where JAX's default backend is the CPU, two cases short-circuit to
+    the NumPy word evaluator instead: tiny programs, per call
+    (``_NP_CUTOFF_WIRE_OPS``), and 64-bit programs containing division —
+    x86 has no SIMD integer divide, so the pair evaluator's Knuth long
+    division scalarizes the fused XLA loop (~100 elementwise passes per
+    divmod), while NumPy's hardware 64-bit ``divq`` is one pass. Both
+    reasons are facts about the host CPU, so on an accelerator every
+    call runs jitted on the device."""
     layout = program.layout
     n_ops = max(1, len(program.ops))
-    np_div64 = layout.word_bits == 64 and any(
+    host_cpu = jax.default_backend() == "cpu"
+    np_div64 = host_cpu and layout.word_bits == 64 and any(
         op.opcode in ("div", "mod", "divmod") for op in program.ops)
 
     if layout.word_bits == 32:
@@ -818,10 +844,13 @@ def build_words_pipeline(program: FusedProgram, donate: bool = False):
             program, [layout.from_wire(np.asarray(x)) for x in leaves])
         return tuple(layout.to_wire(o) for o in outs)
 
+    def small(wire_words):
+        return host_cpu and wire_words * n_ops <= _NP_CUTOFF_WIRE_OPS
+
     def word_pipeline(*leaves):
         if np_div64:
             return np_words(*leaves)
-        if leaves and leaves[0].size * n_ops <= _NP_CUTOFF_WIRE_OPS \
+        if leaves and small(leaves[0].size) \
                 and all(isinstance(x, np.ndarray) for x in leaves):
             return np_words(*leaves)
         return jitted(*leaves)
@@ -831,7 +860,7 @@ def build_words_pipeline(program: FusedProgram, donate: bool = False):
     # jitted — and never into a donating trace.
     word_pipeline.wants_device = (
         lambda wire_words: not donate and not np_div64
-        and wire_words * n_ops > _NP_CUTOFF_WIRE_OPS)
+        and not small(wire_words))
     return word_pipeline
 
 
@@ -844,10 +873,11 @@ def build_sharded_words_pipeline(program: FusedProgram,
     communication-free — GSPMD places each shard's slice of the fused
     elementwise DAG on its device; outputs gather on read-back.
 
-    Leaves pad to a multiple of 32 x n_devices before placement (the
-    engine slices its lane count back out of the outputs, exactly as it
-    does for the 32-lane padding). ``donate`` is ignored: donated input
-    buffers would alias the per-device shards the caller still owns.
+    Leaves pad to a multiple of 32 x n_devices before placement. Outputs
+    stay on the devices: sharded when no padding was needed, otherwise
+    sliced back to the leaves' length on the device. ``donate`` is
+    ignored: donated input buffers would alias the per-device shards the
+    caller still owns.
     """
     from repro.distributed.sharding import words_mesh, words_sharding
 
@@ -872,7 +902,8 @@ def build_sharded_words_pipeline(program: FusedProgram,
         pad = (-n) % (32 * n_dev)
         placed = [jax.device_put(np.pad(np.asarray(x, np.int32), (0, pad)),
                                  sharding) for x in leaves]
-        return tuple(np.asarray(o)[:n] for o in jitted(*placed))
+        outs = jitted(*placed)
+        return outs if not pad else tuple(o[:n] for o in outs)
 
     return sharded_pipeline
 
@@ -887,10 +918,9 @@ def build_vertical_pipeline(program: FusedProgram, use_pallas: bool,
     width = program.width
     layout = program.layout
     if use_pallas:
-        interp = interpret or not _on_tpu()
-        transpose = functools.partial(_pl_transpose, interpret=interp)
+        transpose = functools.partial(_pl_transpose, interpret=interpret)
         run = functools.partial(run_program_pallas, program,
-                                interpret=interp)
+                                interpret=interpret)
     else:
         transpose = ref.bit_transpose32
         run = functools.partial(run_program_ref, program)

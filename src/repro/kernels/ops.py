@@ -1,9 +1,10 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the Pallas path runs natively; on CPU (this container) the wrappers
-dispatch to the jnp oracle by default — Pallas interpret mode executes the
-kernel body in Python per grid step and is for validation, not speed. Tests
-exercise interpret=True explicitly (tests/kernels/).
+On TPU the Pallas path runs natively; elsewhere the wrappers dispatch to the
+jnp oracle by default. ``force_pallas=True`` off the TPU needs
+``interpret=True`` as well: Pallas interpret mode executes the kernel body
+in Python per grid step and is for validation, not speed, so it runs only
+when the caller asks for it (tests/kernels/ do).
 
 Layout conventions (docs/architecture.md): *horizontal* operands are flat
 packed words (element i = word i); *vertical* operands are bit-plane
@@ -28,28 +29,27 @@ from repro.kernels.maj_n import maj_n as _pl_maj
 def maj_n(x, threshold: int, force_pallas: bool = False,
           interpret: bool = False):
     if _on_tpu() or force_pallas:
-        return _pl_maj(x, threshold, interpret=interpret or not _on_tpu())
+        return _pl_maj(x, threshold, interpret=interpret)
     # CPU: the bit-sliced form beats the unpack-sum oracle ~20x (§Perf K0).
     return ref.maj_n_fast(x, threshold)
 
 
 def bitserial_add(a, b, force_pallas: bool = False, interpret: bool = False):
     if _on_tpu() or force_pallas:
-        return _pl_add(a, b, interpret=interpret or not _on_tpu())
+        return _pl_add(a, b, interpret=interpret)
     return ref.bitserial_add(a, b)
 
 
 def bit_transpose32(x, force_pallas: bool = False, interpret: bool = False):
     if _on_tpu() or force_pallas:
-        return _pl_transpose(x, interpret=interpret or not _on_tpu())
+        return _pl_transpose(x, interpret=interpret)
     return ref.bit_transpose32(x)
 
 
 def charge_share(v, caps, *, vdd: float, c_bl: float,
                  force_pallas: bool = False, interpret: bool = False):
     if _on_tpu() or force_pallas:
-        return _pl_cs(v, caps, vdd=vdd, c_bl=c_bl,
-                      interpret=interpret or not _on_tpu())
+        return _pl_cs(v, caps, vdd=vdd, c_bl=c_bl, interpret=interpret)
     return ref.charge_share(v, caps, vdd=vdd, c_bl=c_bl)
 
 
@@ -62,6 +62,5 @@ def run_fused_program(program: FusedProgram, x, force_pallas: bool = False,
     ``fused_program.get_pipeline`` instead: on CPU it switches to the
     word-domain evaluator, which is the actual speed path."""
     if _on_tpu() or force_pallas:
-        return run_program_pallas(program, x,
-                                  interpret=interpret or not _on_tpu())
+        return run_program_pallas(program, x, interpret=interpret)
     return run_program_ref(program, x)

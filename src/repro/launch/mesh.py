@@ -10,19 +10,27 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis Auto: the sharding rules here are
+    GSPMD constraints, not the explicit-axis typing that ``make_mesh``
+    defaults to."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int | None = None):
     """Small mesh over whatever devices exist (CPU tests)."""
     n = n_devices or len(jax.devices())
     if n % 2 == 0:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((n, 1), ("data", "model"))
+        return _auto_mesh((n // 2, 2), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

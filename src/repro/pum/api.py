@@ -29,6 +29,7 @@ contract, now behind one type.
 from __future__ import annotations
 
 import contextlib
+import warnings
 
 import numpy as np
 
@@ -64,13 +65,16 @@ class Device:
         # width/layout pair (a pinned fused_backend that covers it takes
         # precedence): fall back to per-op eager execution instead of
         # refusing to build — EngineConfig-valid widths up to 64 always
-        # yield a working device.
+        # yield a working device — and say so, since the eager path
+        # computes on the host.
         if config.fuse and config.fused_backend is None:
             from repro.backends import select_backend
             try:
                 select_backend(require="fused", width=config.width,
                                layout=config.resolved_layout())
-            except LookupError:
+            except LookupError as e:
+                warnings.warn(f"{e}; this device runs eager (fuse=False)",
+                              RuntimeWarning, stacklevel=3)
                 config = config.replace(fuse=False)
         self.config = config
         if _engine is None:

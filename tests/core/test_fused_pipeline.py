@@ -524,6 +524,66 @@ def test_autoflush_memory_threshold():
         & np.uint64(0xFFFFFFFF))
 
 
+@pytest.mark.parametrize("width", [8, 32, 64])
+def test_autoflush_vmem_block_bound(width):
+    """With no op or memory bound, the Pallas kernel's VMEM block still
+    bounds every flush: a long chain splits into programs whose leaves and
+    ops fit the block budget, results and stats unchanged."""
+    from repro.telemetry import Tracer
+    rng = np.random.default_rng(57)
+    pool = rng.integers(0, 1 << min(width, 63), (1200, 32), dtype=np.uint64)
+    eager = PulsarEngine(width=width)
+    fused = PulsarEngine(width=width, fuse=True, flush_threshold=None,
+                         flush_memory_bytes=None)
+    fused.tracer = Tracer()
+
+    def run(e):
+        t = pool[0]
+        for x in pool[1:]:
+            t = e.xor(t, x)
+        return np.asarray(t, np.uint64)
+
+    np.testing.assert_array_equal(run(eager), run(fused))
+    assert eager.stats == fused.stats
+    c = fused.counters
+    assert c["engine.autoflush.vmem"] >= 1
+    biggest = c.histogram("engine.flush_ops")["max"]
+    # one leaf per op, plus the chain's first operand
+    assert fused_program.block_bytes(2 * biggest + 1, width) \
+        < fused_program.VMEM_BLOCK_BUDGET + fused_program.block_bytes(
+            3, width)
+
+
+@pytest.mark.parametrize("div", [False, True], ids=["xor32", "div64"])
+def test_word_pipeline_numpy_short_circuits_only_on_cpu(monkeypatch, div):
+    """The small-program and 64-bit-divide NumPy paths exist for the host
+    CPU: where JAX runs on an accelerator, every call runs jitted."""
+    import jax
+    from repro.kernels.plane_layout import LAYOUT32, LAYOUT64
+    layout = LAYOUT64 if div else LAYOUT32
+    prog = fused_program.FusedProgram(
+        width=layout.word_bits, n_inputs=2,
+        ops=(fused_program.FusedOp("div" if div else "xor", (0, 1)),),
+        outputs=(2,), layout=layout)
+    rng = np.random.default_rng(59)
+    a, b = (rng.integers(1, 1 << 32, 64, dtype=np.uint64) for _ in range(2))
+    leaves = [layout.to_wire(x.astype(layout.np_dtype)) for x in (a, b)]
+    want = a // b if div else a ^ b
+
+    def lanes(out):
+        return layout.from_wire(out)[:64].astype(np.uint64)
+
+    cpu = fused_program.build_words_pipeline(prog)
+    (out,) = cpu(*leaves)
+    assert isinstance(out, np.ndarray) and not cpu.wants_device(64)
+    np.testing.assert_array_equal(lanes(out), want)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    chip = fused_program.build_words_pipeline(prog)
+    (out,) = chip(*leaves)
+    assert isinstance(out, jax.Array) and chip.wants_device(64)
+    np.testing.assert_array_equal(lanes(out), want)
+
+
 def test_autoflush_disabled_with_none():
     e = PulsarEngine(width=16, fuse=True, flush_threshold=None,
                      flush_memory_bytes=None)

@@ -78,7 +78,7 @@ def test_wide_device_fuses_on_the_64bit_layout():
 
 def test_device_falls_back_to_eager_without_a_layout_evaluator():
     """When NO registered fused evaluator supports the device's layout,
-    fuse transparently downgrades to eager (the pre-width-64 behavior,
+    fuse downgrades to eager with a warning (the pre-width-64 behavior,
     now reachable only by unregistering the 64-bit evaluators)."""
     saved = {n: pum.get_backend(n)
              for n in ("words-cpu-64", "pallas-tpu-64", "ref-vertical-64")}
@@ -87,7 +87,8 @@ def test_device_falls_back_to_eager_without_a_layout_evaluator():
     try:
         with pytest.raises(LookupError, match="64-bit plane layout"):
             pum.select_backend(require="fused", width=48, layout=64)
-        dev = pum.device(width=48)
+        with pytest.warns(RuntimeWarning, match="runs eager"):
+            dev = pum.device(width=48)
         assert not dev.config.fuse
         a = np.array([1 << 40, 5], np.uint64)
         np.testing.assert_array_equal(np.asarray(dev.asarray(a) + a),
@@ -228,6 +229,34 @@ def test_register_backend_is_additive_and_selectable():
         assert len(calls) == 2  # fresh spec -> fresh compile, no stale hit
     finally:
         pum.unregister_backend("test-words")
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_has_a_fixed_home(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; unset, the cache
+    goes to .jax_cache at the root of the checkout, the same every call."""
+    import os
+
+    import jax
+    from repro.backends import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = use_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert use_compile_cache() == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_unknown_eager_backend_fails_loudly():

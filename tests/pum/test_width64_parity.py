@@ -414,9 +414,12 @@ def test_shard_words_multidevice_parity():
             t = (x + b) * x
             q, r = divmod(t, b)
             return [np.asarray(v) for v in (t, q, r, t.popcount())]
-        for w, g in zip(run(eager), run(fused)):
-            np.testing.assert_array_equal(w, g)
+        with pum.profile(fused):
+            for w, g in zip(run(eager), run(fused)):
+                np.testing.assert_array_equal(w, g)
         assert eager.stats == fused.stats
+        # outputs come back sharded across every device
+        assert fused.counters.histogram("engine.flush_devices")["min"] == 8
         print("OK")
     """)
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
