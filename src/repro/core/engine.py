@@ -70,12 +70,13 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import threading
-import time
 import warnings
 import weakref
 from concurrent.futures import ThreadPoolExecutor
 
+import jax
 import numpy as np
 
 from repro.backends import get_backend, select_backend
@@ -477,9 +478,12 @@ class _OpGraph:
         self.cache_evictions = 0
         self.ops: list[tuple[str, tuple, int]] = []  # (opcode, args, param)
         self.results: list = []         # weakref per op
-        # perf_counter_ns at first recorded op — set only when a tracer is
-        # attached, so flush() can emit the "flush.record" span.
-        self.t_start: int | None = None
+        # Set only while a tracer is attached: the engine's sequence
+        # number of this flush (every flush.* span carries it), and the
+        # "flush.record" span, open from the first recorded op until the
+        # flush prepares the graph.
+        self.flush_id: int | None = None
+        self.record = None
         # Flush lifecycle (guarded by the engine lock): "recording" in a
         # client context's slot, "queued" parked on the retry list after a
         # failed flush, "flushing" detached and being dispatched (``done``
@@ -769,6 +773,7 @@ class PulsarEngine:
         # is a single `is None` check per flush, nothing per op.
         self.counters = CounterBank()
         self.tracer = None
+        self._flush_ids = itertools.count()  # flush ids, drawn while traced
         # Autotuner hook: None (default) costs one `is None` check per
         # flush; Device.autotune(online=True) installs an
         # repro.autotune.OnlineAutotuner whose on_flush() closes the
@@ -1125,7 +1130,9 @@ class PulsarEngine:
                     n, self.layout.word_bits if raw else self.width,
                     self.layout, raw=raw, cache=self._leaf_cache)
                 if self.tracer is not None:
-                    g.t_start = time.perf_counter_ns()
+                    g.flush_id = next(self._flush_ids)
+                    g.record = self.tracer.begin("flush.record",
+                                                 flush=g.flush_id)
             if self.tracer is not None:
                 self.counters.inc("engine.ops_recorded")
                 self.counters.inc(f"engine.op.{opcode}")
@@ -1267,8 +1274,6 @@ class PulsarEngine:
             with self._lock:
                 for g, _ in staged:
                     self._inflight.pop(id(g), None)
-        if self.tracer is not None:
-            self.counters.inc("engine.flush_async")
         return FlushHandle(fut)
 
     def close(self) -> None:
@@ -1396,12 +1401,15 @@ class PulsarEngine:
         if not g.ops:
             return None
         tr = NULL_TRACER if self.tracer is None else self.tracer
-        if g.t_start is not None:
-            # The record phase ran between first op and now; stamp it as a
-            # span from the graph's own start time.
-            tr.add_span("flush.record", g.t_start, time.perf_counter_ns(),
-                        n_ops=len(g.ops), n_leaves=len(g.leaves),
-                        raw=g.raw)
+        if self.tracer is not None and g.flush_id is None:
+            g.flush_id = next(self._flush_ids)  # traced from mid-recording
+        fid = g.flush_id
+        rec, g.record = g.record, None
+        if rec is not None:
+            # The record phase ran from the first op until now.
+            rec.args.update(n_ops=len(g.ops), n_leaves=len(g.leaves),
+                            raw=g.raw)
+            rec.__exit__(None, None, None)
         live = [wr() for wr in g.results]
         # Materialize ops whose handle is still referenced; handles that
         # died unreferenced are dead code (their cost was still charged,
@@ -1414,7 +1422,8 @@ class PulsarEngine:
         def vid(tag):  # combined id space: leaves first, then ops
             return tag[1] if tag[0] == "leaf" else n_leaves + tag[1]
 
-        with tr.span("flush.optimize", n_ops_in=len(g.ops)) as sp_opt:
+        with tr.span("flush.optimize", flush=fid,
+                     n_ops_in=len(g.ops)) as sp_opt:
             program = FusedProgram(
                 width=g.width, n_inputs=n_leaves,
                 ops=tuple(FusedOp(opcode, tuple(vid(a) for a in args),
@@ -1424,7 +1433,8 @@ class PulsarEngine:
                 layout=g.layout)
             program, out_pos, leaf_map = optimize_program(program)
             sp_opt.args["n_ops_out"] = len(program.ops)
-        with tr.span("flush.leaf_upload", n_leaves=len(leaf_map)) as sp_up:
+        with tr.span("flush.leaf_upload", flush=fid,
+                     n_leaves=len(leaf_map)) as sp_up:
             # Leaves are already padded wire (or leaf-cache entries) —
             # staging moves no bytes; cache entries resolve to committed
             # device buffers at dispatch (_run_staged).
@@ -1461,7 +1471,8 @@ class PulsarEngine:
         """Dispatch-side half of a flush: compile, run, materialize."""
         program, out_pos, live, out_idx, leaves = staged
         tr = NULL_TRACER if self.tracer is None else self.tracer
-        with tr.span("flush.compile") as sp_c:
+        fid = g.flush_id
+        with tr.span("flush.compile", flush=fid) as sp_c:
             if self.tracer is not None:
                 misses0 = _fused._cached_pipeline.cache_info().misses
             pipeline = get_pipeline(program, donate=self.donate_leaves,
@@ -1474,7 +1485,7 @@ class PulsarEngine:
                 sp_c.args["cache"] = "hit" if hit else "miss"
         leaves = self._resolve_cached_leaves(g, pipeline, leaves)
         rel = self.reliability
-        with tr.span("flush.dispatch", n_ops=len(program.ops),
+        with tr.span("flush.dispatch", flush=fid, n_ops=len(program.ops),
                      n_lanes=g.n) as sp_d:
             if rel is not None and rel.inject:
                 # Fault-injection hook: the pipeline runs once clean
@@ -1487,27 +1498,38 @@ class PulsarEngine:
                 outs = voted(*leaves)
             else:
                 outs = pipeline(*leaves)
-        with tr.span("flush.materialize", n_outputs=len(out_idx)):
-            for i, pos in zip(out_idx, out_pos):
-                lz = live[i]
-                lanes = g.layout.from_wire(outs[pos])[:g.n]
-                if g.raw:  # re-join the lanes of each caller uint64 word
-                    val = g.layout.join_raw(lanes)
-                    if g.ops[i][0] == "popcount" \
-                            and g.layout.raw_lanes_per_word == 2:
-                        # A raw popcount's lanes hold per-lane partial
-                        # counts: the word's count is their SUM (the
-                        # adder tree's final fold), not a bit-join.
-                        val = ((val >> np.uint64(32))
-                               + (val & np.uint64(0xFFFFFFFF)))
-                else:
-                    val = lanes.astype(np.uint64)
-                lz._value = val.reshape(lz.shape)
-                # A materialized handle never needs the graph again — drop
-                # the references so surviving handles don't pin the leaf
-                # snapshots (or the engine) for their lifetime.
-                lz._graph = None
-                lz._engine = None
+        with tr.span("flush.materialize", flush=fid,
+                     n_outputs=len(out_idx)):
+            with tr.span("flush.wait", flush=fid):
+                # The host blocks on the device (host outputs are ready).
+                jax.block_until_ready(outs)
+            with tr.span("flush.fetch", flush=fid) as sp_f:
+                fetched = {pos: np.asarray(outs[pos]) for pos in out_pos}
+                if self.tracer is not None:
+                    sp_f.args["bytes"] = sum(a.nbytes
+                                             for a in fetched.values())
+            with tr.span("flush.unpack", flush=fid):
+                for i, pos in zip(out_idx, out_pos):
+                    lz = live[i]
+                    lanes = g.layout.from_wire(fetched[pos])[:g.n]
+                    if g.raw:  # re-join the lanes of each caller word
+                        val = g.layout.join_raw(lanes)
+                        if g.ops[i][0] == "popcount" \
+                                and g.layout.raw_lanes_per_word == 2:
+                            # A raw popcount's lanes hold per-lane partial
+                            # counts: the word's count is their SUM (the
+                            # adder tree's final fold), not a bit-join.
+                            val = ((val >> np.uint64(32))
+                                   + (val & np.uint64(0xFFFFFFFF)))
+                    else:
+                        val = lanes.astype(np.uint64)
+                    lz._value = val.reshape(lz.shape)
+                    # A materialized handle never needs the graph again —
+                    # drop the references so surviving handles don't pin
+                    # the leaf snapshots (or the engine) for their
+                    # lifetime.
+                    lz._graph = None
+                    lz._engine = None
         if self.tracer is not None:
             self.counters.inc("engine.flushes")
             self.counters.observe("engine.flush_lanes", g.n)

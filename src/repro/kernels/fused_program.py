@@ -925,12 +925,19 @@ def build_vertical_pipeline(program: FusedProgram, use_pallas: bool,
         transpose = ref.bit_transpose32
         run = functools.partial(run_program_ref, program)
 
+    # Each stage under a stable scope, so a profile can tell the layout
+    # copies around the transposes from the kernel.
     def pipeline(*leaves):
-        stack = jnp.stack([layout.pack_planes(leaf, transpose, width)
-                           for leaf in leaves])
-        outs = run(stack)
-        return tuple(layout.unpack_planes(outs[t], transpose, width)
-                     for t in range(outs.shape[0]))
+        with jax.named_scope("pum.to_planes"):
+            planes = [layout.pack_planes(leaf, transpose, width)
+                      for leaf in leaves]
+        with jax.named_scope("pum.stack"):
+            stack = jnp.stack(planes)
+        with jax.named_scope("pum.kernel"):
+            outs = run(stack)
+        with jax.named_scope("pum.from_planes"):
+            return tuple(layout.unpack_planes(outs[t], transpose, width)
+                         for t in range(outs.shape[0]))
 
     fn = _donating(pipeline, program.n_inputs) if donate \
         else jax.jit(pipeline)

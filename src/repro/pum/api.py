@@ -655,7 +655,7 @@ def profile(device: Device | None = None, path: str | None = None):
 
         with pum.profile(path="trace.json") as tr:
             y = pum.asarray(x) + x2
-        print(tr.span_names())   # flush.record ... flush.materialize
+        print(tr.span_names())   # flush.record ... flush.unpack
 
     Profiling is observational only: results, ``Device.stats`` and the
     scheduled command streams are bit-identical with or without it

@@ -1,6 +1,6 @@
 """repro.telemetry — zero-overhead-when-disabled observability.
 
-Three pieces, one contract:
+Four pieces, one contract:
 
 * :class:`CounterBank` — named monotonic counters + log2-bucket
   histograms; the single counter container used by the engine, the
@@ -17,7 +17,10 @@ Three pieces, one contract:
   returning a list of violations (empty = clean).
 * :class:`Tracer` / :data:`NULL_TRACER` — span context-managers around
   the fused pipeline's flush phases, exportable as Chrome trace-event
-  JSON (opens in Perfetto).
+  JSON (opens in Perfetto); every span is also a JAX profiler
+  annotation, so a profiler trace carries them on the device's clock.
+* :func:`process_counters` — process-wide compile counters
+  (``compile.*``), fed by JAX's monitoring events from import on.
 
 See ``docs/observability.md`` for counter definitions, units, and the
 span taxonomy.
@@ -26,6 +29,7 @@ span taxonomy.
 from repro.telemetry.counters import (CounterBank, check_timing_invariants,
                                       derive_controller_counters,
                                       derive_port_counters)
+from repro.telemetry.compiles import process_counters
 from repro.telemetry.tracer import NULL_TRACER, Span, Tracer
 
 __all__ = [
@@ -36,4 +40,5 @@ __all__ = [
     "check_timing_invariants",
     "derive_controller_counters",
     "derive_port_counters",
+    "process_counters",
 ]

@@ -6,28 +6,45 @@ tier's ticks. Export is the Chrome trace-event JSON format
 (``tracer.export("trace.json")``), so any trace opens directly in
 Perfetto / ``chrome://tracing``.
 
+Shared clock: every span of a ``Tracer`` is also a
+``jax.profiler.TraceAnnotation`` of the same name, so while the JAX
+profiler runs (``jax.profiler.start_trace``) the spans land in its
+``.xplane.pb`` beside the device's operations, on the profiler's clock,
+with the span's attributes as the annotation's arguments. A span closed
+on another thread than the one that opened it (a recording handed to
+another thread's flush) is left out of the profiler's trace: its
+annotation is closed only once profiling has stopped, when the profiler
+records nothing. The tracer's own ``perf_counter_ns`` events keep it.
+
 Zero-overhead-when-disabled contract: nothing in the repo constructs a
 ``Tracer`` unless asked (``pum.profile()``, ``ServeEngine(telemetry=
 True)``); instrumented code paths use :data:`NULL_TRACER` when none is
 attached, whose ``span()`` returns a shared no-op context manager — no
-clock reads, no allocation, no event list. Tracing never feeds back into
-scheduling, results, or the cost plane (invariance is tested).
+clock reads, no allocation, no annotation, no event list. Tracing never
+feeds back into scheduling, results, or the cost plane (invariance is
+tested).
 """
 
 from __future__ import annotations
 
 import json
+import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class Span:
-    """One open span: a context manager stamping enter/exit wall time.
+    """One open span: a context manager stamping enter/exit wall time
+    inside a profiler annotation of the same name.
 
     After exit, ``dur_ns`` holds the span duration (integer nanoseconds);
     callers feed it into ``CounterBank.observe`` for latency histograms.
+    ``args`` set before exit reach both the tracer's event and the
+    profiler's annotation.
     """
 
-    __slots__ = ("_tracer", "name", "args", "_t0", "dur_ns")
+    __slots__ = ("_tracer", "name", "args", "_t0", "dur_ns", "_ann", "_tid")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -37,6 +54,8 @@ class Span:
         self.dur_ns = 0
 
     def __enter__(self) -> "Span":
+        self._ann = TraceAnnotation(self.name)
+        self._tid = threading.get_ident()
         self._t0 = time.perf_counter_ns()
         return self
 
@@ -44,6 +63,12 @@ class Span:
         t1 = time.perf_counter_ns()
         self.dur_ns = t1 - self._t0
         self._tracer._events.append((self.name, self._t0, t1, self.args))
+        if self.args:
+            self._ann.set_metadata(**self.args)
+        if threading.get_ident() == self._tid:
+            self._ann.__exit__(None, None, None)
+        else:
+            self._tracer._parked.append(self._ann)
 
 
 class _NullSpan:
@@ -80,12 +105,6 @@ class _NullTracer:
     def span(self, name: str, **args) -> _NullSpan:
         return _NULL_SPAN
 
-    def add_span(self, name: str, t0_ns: int, t1_ns: int, **args) -> None:
-        pass
-
-    def instant(self, name: str, **args) -> None:
-        pass
-
 
 NULL_TRACER = _NullTracer()
 
@@ -100,32 +119,34 @@ class Tracer:
     ['phase']
     """
 
-    __slots__ = ("_events",)
+    __slots__ = ("_events", "_parked")
 
     def __init__(self):
         # (name, t0_ns, t1_ns, args) — perf_counter_ns timestamps.
         self._events: list[tuple[str, int, int, dict]] = []
+        # Annotations of spans that ended on another thread, held open
+        # until the profiler stops (see the module docstring).
+        self._parked: list[TraceAnnotation] = []
 
     @property
     def events(self) -> list[tuple[str, int, int, dict]]:
-        """Recorded spans as ``(name, t0_ns, t1_ns, args)`` tuples
-        (instants have ``t1_ns == t0_ns``)."""
+        """Recorded spans as ``(name, t0_ns, t1_ns, args)`` tuples."""
         return list(self._events)
 
     def span(self, name: str, **args) -> Span:
         """Context manager timing one named phase."""
+        if self._parked and not TraceAnnotation.is_enabled():
+            for ann in self._parked:
+                ann.__exit__(None, None, None)
+            self._parked.clear()
         return Span(self, name, args)
 
-    def add_span(self, name: str, t0_ns: int, t1_ns: int, **args) -> None:
-        """Record a span from explicit ``perf_counter_ns`` timestamps
-        (used for phases whose start predates the tracer's attention,
-        e.g. the record phase stamped at first-op time)."""
-        self._events.append((name, t0_ns, t1_ns, args))
-
-    def instant(self, name: str, **args) -> None:
-        """Record a zero-duration marker."""
-        now = time.perf_counter_ns()
-        self._events.append((name, now, now, args))
+    def begin(self, name: str, **args) -> Span:
+        """Open a span now and hand it back; the caller exits it later
+        (``span.__exit__(None, None, None)``), possibly from another
+        function. For phases with no enclosing block, e.g. a flush's
+        record phase, which opens at the first recorded op."""
+        return self.span(name, **args).__enter__()
 
     def span_names(self) -> list[str]:
         """Names of recorded spans, in start order."""

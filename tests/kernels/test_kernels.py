@@ -266,6 +266,19 @@ def test_fused_pipeline_end_to_end():
         np.testing.assert_array_equal(np.asarray(got), np.asarray(gvert))
 
 
+def test_pallas_pipeline_stages_carry_named_scopes():
+    """Each stage of the pallas-tpu pipeline lowers under a stable scope
+    name, so a profile can tell the layout copies from the kernel."""
+    vals, _ = _fused_demo_stacks(512, seed=2)
+    leaves = [jnp.asarray(v.astype(np.uint32).view(np.int32)) for v in vals]
+    pipeline = get_pipeline(_FUSED_DEMO, backend="pallas-tpu",
+                            interpret=True)
+    text = jax.jit(pipeline).lower(*leaves).as_text(debug_info=True)
+    for scope in ("pum.stack", "pum.to_planes", "pum.kernel",
+                  "pum.from_planes"):
+        assert scope in text, scope
+
+
 @given(seed=st.integers(0, 100))
 @settings(max_examples=15, deadline=None)
 def test_fused_plane_algebra_property(seed):

@@ -2,6 +2,8 @@
 EngineStats, and identical scheduled command traces whether telemetry is
 attached or not — across widths, eager vs fused, and controller="auto"."""
 
+import types
+
 import numpy as np
 import pytest
 
@@ -50,6 +52,29 @@ def test_profile_invariance_with_controller_auto():
     b = rng.integers(1, 1 << 16, 200, dtype=np.uint64)
     base, stats_base = _run(16, True, "auto", False, a, b)
     prof, stats_prof = _run(16, True, "auto", True, a, b)
+    np.testing.assert_array_equal(base, prof)
+    assert stats_base == stats_prof
+
+
+@pytest.mark.parametrize("width", [16, 64])
+def test_null_tracer_creates_no_annotation_and_reads_no_clock(width,
+                                                              monkeypatch):
+    """With no tracer attached the flush path makes no profiler
+    annotation and reads no tracer clock, and its results are
+    bit-identical to a profiled run's."""
+    from repro.telemetry import tracer as tracer_mod
+    rng = np.random.default_rng(width + 1)
+    a = rng.integers(0, 1 << min(width, 63), 300, dtype=np.uint64)
+    b = rng.integers(1, 1 << min(width, 63), 300, dtype=np.uint64)
+    prof, stats_prof = _run(width, True, None, True, a, b)
+
+    def forbidden(*args, **kwargs):
+        raise AssertionError("the untraced flush path reached the tracer")
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(tracer_mod, "time",
+                        types.SimpleNamespace(perf_counter_ns=forbidden))
+    base, stats_base = _run(width, True, None, False, a, b)
     np.testing.assert_array_equal(base, prof)
     assert stats_base == stats_prof
 

@@ -8,7 +8,7 @@ import pytest
 
 import repro.pum as pum
 from repro.kernels import fused_program as _fused
-from repro.telemetry import NULL_TRACER, Tracer
+from repro.telemetry import NULL_TRACER, CounterBank, Tracer
 
 pytestmark = pytest.mark.fused
 
@@ -36,17 +36,14 @@ def test_null_tracer_is_inert():
         sp.args["y"] = 2       # writes vanish; no shared state mutated
     assert sp.dur_ns == 0
     assert sp.args == {}
-    NULL_TRACER.instant("e")
-    NULL_TRACER.add_span("s", 0, 5)
 
 
 def test_chrome_export_shape(tmp_path):
     tr = Tracer()
     with tr.span("alpha", k="v"):
         pass
-    tr.instant("tick")
     path = tmp_path / "trace.json"
-    tr.export(str(path))
+    tr.export(str(path), counters=CounterBank())
     doc = json.loads(path.read_text())
     assert doc["displayTimeUnit"] == "ms"
     evs = doc["traceEvents"]
@@ -55,7 +52,9 @@ def test_chrome_export_shape(tmp_path):
     assert [e["name"] for e in complete] == ["alpha"]
     assert complete[0]["args"] == {"k": "v"}
     assert complete[0]["dur"] >= 0          # microseconds
-    assert [e["name"] for e in instants] == ["tick"]
+    assert [e["name"] for e in instants] == ["counters"]
+    assert instants[0]["s"] == "g"
+    assert instants[0]["args"] == {"counters": {}, "histograms": {}}
 
 
 # --------------------------------------------------------------------- #
