@@ -245,8 +245,7 @@ class LazyArray:
         return self._value
 
     def __array__(self, dtype=None, copy=None):
-        v = self.materialize()
-        return v.astype(dtype) if dtype is not None else v
+        return _as_array(self.materialize(), dtype, copy)
 
     # ndarray conveniences the app kernels lean on: each materializes
     # (flushing the graph) and delegates — results are plain ndarrays.
@@ -275,6 +274,39 @@ class LazyArray:
     def __repr__(self) -> str:
         state = "pending" if self._value is None else "materialized"
         return f"LazyArray(shape={self.shape}, {state})"
+
+
+def _as_array(v: np.ndarray, dtype, copy) -> np.ndarray:
+    """``v`` under NumPy's ``__array__(dtype, copy)`` protocol: ``v``
+    itself when it already has ``dtype`` and no copy is asked for."""
+    if dtype is None or np.dtype(dtype) == v.dtype:
+        return v.copy() if copy else v
+    if copy is False:
+        raise ValueError(f"{v.dtype} -> {np.dtype(dtype)} needs a copy")
+    return v.astype(dtype)
+
+
+def _unpack_output(layout: PlaneLayout, wire: np.ndarray, n: int,
+                   raw: bool, popcount: bool) -> np.ndarray:
+    """One fetched flush output -> the caller's flat ``uint64`` value, in
+    one host pass into one fresh buffer (the fetched wire is read-only and
+    owned by JAX; callers own what they get)."""
+    lanes = layout.from_wire(wire)[:n]
+    if not raw:
+        return lanes.astype(np.uint64)
+    if popcount and layout.raw_lanes_per_word == 2:
+        # A raw popcount's lanes hold per-lane partial counts: the word's
+        # count is their SUM (the adder tree's final fold), not a
+        # bit-join. Lane 2k is word k's low half, lane 2k+1 its high half.
+        return np.add(lanes[0::2], lanes[1::2], dtype=np.uint64)
+    return layout.join_raw(lanes)  # re-join the lanes of each word
+
+
+def _buffer_nbytes(a: np.ndarray) -> int:
+    """Bytes of the ndarray that owns ``a``'s memory."""
+    while isinstance(a.base, np.ndarray):
+        a = a.base
+    return a.nbytes
 
 
 def _DEAD_REF():  # weakref stand-in for ops that must never be outputs
@@ -1508,21 +1540,11 @@ class PulsarEngine:
                 if self.tracer is not None:
                     sp_f.args["bytes"] = sum(a.nbytes
                                              for a in fetched.values())
-            with tr.span("flush.unpack", flush=fid):
+            with tr.span("flush.unpack", flush=fid) as sp_u:
                 for i, pos in zip(out_idx, out_pos):
                     lz = live[i]
-                    lanes = g.layout.from_wire(fetched[pos])[:g.n]
-                    if g.raw:  # re-join the lanes of each caller word
-                        val = g.layout.join_raw(lanes)
-                        if g.ops[i][0] == "popcount" \
-                                and g.layout.raw_lanes_per_word == 2:
-                            # A raw popcount's lanes hold per-lane partial
-                            # counts: the word's count is their SUM (the
-                            # adder tree's final fold), not a bit-join.
-                            val = ((val >> np.uint64(32))
-                                   + (val & np.uint64(0xFFFFFFFF)))
-                    else:
-                        val = lanes.astype(np.uint64)
+                    val = _unpack_output(g.layout, fetched[pos], g.n, g.raw,
+                                         g.ops[i][0] == "popcount")
                     lz._value = val.reshape(lz.shape)
                     # A materialized handle never needs the graph again —
                     # drop the references so surviving handles don't pin
@@ -1530,6 +1552,9 @@ class PulsarEngine:
                     # lifetime.
                     lz._graph = None
                     lz._engine = None
+                if self.tracer is not None:
+                    sp_u.args["bytes"] = sum(
+                        _buffer_nbytes(live[i]._value) for i in out_idx)
         if self.tracer is not None:
             self.counters.inc("engine.flushes")
             self.counters.observe("engine.flush_lanes", g.n)

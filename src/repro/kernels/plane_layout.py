@@ -129,9 +129,14 @@ class PlaneLayout:
         return np.ascontiguousarray(words).view(self.np_dtype)
 
     def join_raw(self, lanes: np.ndarray) -> np.ndarray:
-        """Inverse of :meth:`raw_lanes` (always copies — callers own the
-        result)."""
-        return np.ascontiguousarray(lanes).copy().view(np.uint64)
+        """Inverse of :meth:`raw_lanes`, in exactly one copy: callers own
+        the result, and a flush output's lanes are a read-only view of the
+        wire fetched from the device, owned by JAX. Every raw flush output
+        but a 32-bit layout's popcount comes back through this copy; that
+        popcount instead sums each word's two lane counts into a fresh
+        array (``core/engine.py`` ``_unpack_output``), and non-raw outputs
+        widen to ``uint64`` with ``astype``, also one pass."""
+        return np.array(lanes, copy=True, order="C").view(np.uint64)
 
     # ------------------------------------------------------------------ #
     # Vertical packing: horizontal wire words <-> bit planes

@@ -33,7 +33,7 @@ import warnings
 
 import numpy as np
 
-from repro.core.engine import LazyArray
+from repro.core.engine import LazyArray, _as_array
 from repro.pum.config import EngineConfig
 
 # Innermost active `with device(...)` last; module default built lazily.
@@ -466,12 +466,11 @@ class PumArray:
 
     def to_numpy(self) -> np.ndarray:
         """The value as a uint64 ndarray (flushes the fused graph if this
-        handle is pending)."""
+        handle is pending): the held value itself, not a copy."""
         return np.asarray(self._data, np.uint64)
 
     def __array__(self, dtype=None, copy=None):
-        v = self.to_numpy()
-        return v.astype(dtype) if dtype is not None else v
+        return _as_array(self.to_numpy(), dtype, copy)
 
     def sum(self, *args, **kw):
         return self.to_numpy().sum(*args, **kw)

@@ -9,8 +9,11 @@ try:
 except ModuleNotFoundError:  # optional dep: fixed-seed fallback
     from repro.testing import given, settings, st
 
-from repro.core.engine import LazyArray, PulsarEngine, _vec_popcount
+import repro.pum as pum
+from repro.core.engine import (LazyArray, PulsarEngine, _buffer_nbytes,
+                               _unpack_output, _vec_popcount)
 from repro.kernels import fused_program
+from repro.kernels.plane_layout import LAYOUT32, LAYOUT64
 
 pytestmark = pytest.mark.fused
 
@@ -293,6 +296,78 @@ def test_fused_raw_popcount_folds_lane_counts():
         composed = np.asarray(e._mul(pc, np.full_like(a, 2)), np.uint64)
         np.testing.assert_array_equal(np.asarray(pc, np.uint64), want)
         np.testing.assert_array_equal(composed, want * 2)
+
+
+# Edge words of the unpack: zero, all ones (count 64), the high bit,
+# each 32-bit half full, and random words.
+_EDGE_WORDS = np.concatenate([
+    np.array([0, 2**64 - 1, 1 << 63, 0xFFFFFFFF, 0xFFFFFFFF << 32, 1],
+             np.uint64),
+    np.random.default_rng(41).integers(0, 2**64, 58, dtype=np.uint64)])
+
+
+def _lane_counts(lanes):
+    return np.array([bin(int(v)).count("1") for v in lanes],
+                    lanes.dtype)
+
+
+@pytest.mark.parametrize("layout", [LAYOUT32, LAYOUT64],
+                         ids=lambda l: l.name)
+@pytest.mark.parametrize("raw", [False, True], ids=["value", "raw"])
+@pytest.mark.parametrize("popcount", [False, True],
+                         ids=["bitjoin", "popcount"])
+def test_unpack_output_is_one_fresh_writable_buffer(layout, raw, popcount):
+    """The unpack of one fetched output: exact against NumPy on edge
+    words, and one fresh writable buffer of the value's own size that
+    shares no memory with the fetched wire (read-only, like a fetched
+    device array's)."""
+    words = _EDGE_WORDS if raw else _EDGE_WORDS & np.uint64(0xFFFF)
+    if raw:
+        lanes = layout.raw_lanes(words)
+        if popcount:  # the evaluators' per-lane partial counts
+            lanes = _lane_counts(lanes)
+        want = _vec_popcount(words) if popcount else words
+    else:  # a value lane holds the value (or its count) itself
+        lanes = (_vec_popcount(words) if popcount else words
+                 ).astype(layout.np_dtype)
+        want = lanes.astype(np.uint64)
+    n = lanes.size
+    padded = np.concatenate([lanes, np.full(32, 7, layout.np_dtype)])
+    wire = layout.to_wire(padded)
+    wire.setflags(write=False)
+    got = _unpack_output(layout, wire, n, raw, popcount)
+    assert got.dtype == np.uint64
+    np.testing.assert_array_equal(got, want)
+    assert got.flags.writeable and not np.shares_memory(got, wire)
+    assert _buffer_nbytes(got) == got.nbytes == words.nbytes
+
+
+@pytest.mark.parametrize("layout", [32, 64])
+@pytest.mark.parametrize("raw", [False, True], ids=["value", "raw"])
+@pytest.mark.parametrize("popcount", [False, True],
+                         ids=["bitjoin", "popcount"])
+def test_flushed_values_are_exact_writable_and_handed_off_uncopied(
+        layout, raw, popcount):
+    """Through a device: each flushed value equals NumPy's on edge words
+    and is writable; ``to_numpy()`` hands off the materialized value
+    itself and ``np.array(x, copy=True)`` a private copy."""
+    a = _EDGE_WORDS if raw else _EDGE_WORDS & np.uint64(0xFFFF)
+    b = np.roll(a, 1) | a  # keeps all ones, the high bit and zero
+    dev = pum.device(width=16, fuse=True, layout=layout)
+    y = dev.asarray(a) & b
+    if popcount:
+        y = y.popcount(width=64 if raw else None)
+    assert isinstance(y._data, LazyArray)
+    assert y._data._graph.raw == raw
+    got = y.to_numpy()
+    want = _vec_popcount(a & b) if popcount else a & b
+    np.testing.assert_array_equal(got, want)
+    assert got.dtype == np.uint64 and got.flags.writeable
+    assert np.shares_memory(got, y._data._value)
+    assert np.shares_memory(np.asarray(y._data, np.uint64), got)
+    private = np.array(y, copy=True)
+    assert not np.shares_memory(private, got)
+    np.testing.assert_array_equal(private, want)
 
 
 def test_fused_planewise_raw_bitmap_path():

@@ -62,7 +62,6 @@ def test_null_tracer_creates_no_annotation_and_reads_no_clock(width,
     """With no tracer attached the flush path makes no profiler
     annotation and reads no tracer clock, and its results are
     bit-identical to a profiled run's."""
-    from repro.telemetry import tracer as tracer_mod
     rng = np.random.default_rng(width + 1)
     a = rng.integers(0, 1 << min(width, 63), 300, dtype=np.uint64)
     b = rng.integers(1, 1 << min(width, 63), 300, dtype=np.uint64)
@@ -71,12 +70,54 @@ def test_null_tracer_creates_no_annotation_and_reads_no_clock(width,
     def forbidden(*args, **kwargs):
         raise AssertionError("the untraced flush path reached the tracer")
 
-    monkeypatch.setattr(tracer_mod, "TraceAnnotation", forbidden)
-    monkeypatch.setattr(tracer_mod, "time",
-                        types.SimpleNamespace(perf_counter_ns=forbidden))
+    _forbid_tracing(monkeypatch, forbidden)
     base, stats_base = _run(width, True, None, False, a, b)
     np.testing.assert_array_equal(base, prof)
     assert stats_base == stats_prof
+
+
+def _forbid_tracing(monkeypatch, forbidden):
+    """Make the tracer's annotations and clock, and the unpack's ``bytes``
+    reading, raise when reached."""
+    from repro.core import engine as engine_mod
+    from repro.telemetry import tracer as tracer_mod
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(tracer_mod, "time",
+                        types.SimpleNamespace(perf_counter_ns=forbidden))
+    monkeypatch.setattr(engine_mod, "_buffer_nbytes", forbidden)
+
+
+def _raw_popcount_flush(dev, a, b):
+    """A bitmap-index flush: a raw AND and its popcount, both live."""
+    acc = dev.asarray(a) & b
+    pc = acc.popcount(width=64)
+    return acc.to_numpy(), pc.to_numpy()
+
+
+@pytest.mark.parametrize("layout", [32, 64])
+def test_unpack_bytes_are_the_outputs_own(layout, monkeypatch):
+    """A traced raw popcount flush's ``flush.unpack`` names the host bytes
+    its values own: one buffer of each output's size. Untraced, the same
+    flush reads no clock, computes no ``bytes`` and gives the same
+    values."""
+    rng = np.random.default_rng(layout)
+    a = rng.integers(0, 2**64, 1000, dtype=np.uint64)
+    b = rng.integers(0, 2**64, 1000, dtype=np.uint64)
+    dev = pum.device(width=16, fuse=True, layout=layout)
+    with pum.profile(dev) as tr:
+        outs = _raw_popcount_flush(dev, a, b)
+    unpack = [args for name, *_, args in tr.events
+              if name == "flush.unpack"]
+    assert len(unpack) == 1
+    assert unpack[0]["bytes"] == sum(o.nbytes for o in outs) == 2 * a.nbytes
+
+    def forbidden(*args, **kwargs):
+        raise AssertionError("the untraced flush path reached the tracer")
+
+    _forbid_tracing(monkeypatch, forbidden)
+    dev = pum.device(width=16, fuse=True, layout=layout)
+    for got, want in zip(_raw_popcount_flush(dev, a, b), outs):
+        np.testing.assert_array_equal(got, want)
 
 
 def test_counters_not_populated_without_tracer():
