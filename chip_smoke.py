@@ -20,8 +20,10 @@ One chip:
   (b) range scan: ``bitweaving_scan`` over 2**26 rows of 16-bit values
       in 32-bit lanes.
 Four chips (``--chips 4``): phase (a) through ``shard-words`` across the
-four chips, whose output shards must sit on four distinct devices, and
-the same query through ``pallas-tpu`` on device 0; both counts must agree.
+four chips, whose output shards must sit on four distinct devices, whose
+cached leaves must be committed split over the four, and whose second
+run must place no leaf byte (the leaves stay resident); then the same
+query through ``pallas-tpu`` on device 0; both counts must agree.
 
 The script fails before any work unless JAX's first device is a TPU. The
 last line of standard output is one JSON object naming the device, and
@@ -105,7 +107,8 @@ def peak_bytes(jax) -> int:
 def run_bmi(phase, pum, realworld, jax, np, days, want, runs=BMI_RUNS,
             **device_kw):
     """Run the bitmap-index query ``runs`` times on a fresh device and
-    return (count, counters, evaluator name)."""
+    return (count, counters, evaluator name, leaf bytes placed by each
+    run, the devices each committed leaf spans)."""
     dev = pum.device(width=32, **DEVICE_KW, **device_kw)
     if not dev.config.fuse:
         fail(f"{phase}: the device fell back to eager execution")
@@ -114,21 +117,28 @@ def run_bmi(phase, pum, realworld, jax, np, days, want, runs=BMI_RUNS,
     log(phase, f"evaluator={name} days={DAYS} users={USERS} "
                f"bitmaps_mib={days.nbytes >> 20}")
     got = None
+    placed = []
     with pum.profile(dev):
         for i in range(runs):
+            before = dev.counters.get("engine.leaf_bytes_placed")
             t0 = time.perf_counter()
             got, _, _ = realworld.bmi_active_users(dev, days, verify=True)
             wall = time.perf_counter() - t0
             if got != want:
                 fail(f"{phase}: run {i + 1} counted {got} active users, "
                      f"NumPy counts {want}")
+            placed.append(int(dev.counters.get("engine.leaf_bytes_placed")
+                              - before))
             log(phase, f"run {i + 1}: active_users={got} (numpy {want}) "
-                       f"wall_s={wall:.6f} "
+                       f"wall_s={wall:.6f} leaf_bytes_placed={placed[-1]} "
                        f"({'first call' if i == 0 else 'warm'}, "
                        f"informational)")
     counters = dev.counters.snapshot()
+    leaf_devices = [len(e.dev.sharding.device_set)
+                    for e in dev.engine._leaf_cache._entries.values()
+                    if e.dev is not None]
     dev.close()
-    return got, counters, name
+    return got, counters, name, placed, leaf_devices
 
 
 def one_chip(pum, realworld, jax, np, seed: int) -> None:
@@ -136,7 +146,7 @@ def one_chip(pum, realworld, jax, np, seed: int) -> None:
     days, want = bmi_data(rng, np)
 
     # (a) the default evaluator: pallas-tpu on a TPU.
-    _, c, name = run_bmi("bmi", pum, realworld, jax, np, days, want)
+    _, c, name, _, _ = run_bmi("bmi", pum, realworld, jax, np, days, want)
     if name != "pallas-tpu":
         fail(f"bmi: select_backend picked {name!r}, not 'pallas-tpu'")
     if c.get("engine.leaf_cache.hits") <= 0:
@@ -150,8 +160,8 @@ def one_chip(pum, realworld, jax, np, seed: int) -> None:
         + f" flush_devices={lo}..{hi} peak_bytes_in_use={peak_bytes(jax)}")
 
     # (a') the word evaluator pinned: jitted on the chip, never NumPy.
-    _, c, name = run_bmi("bmi-words", pum, realworld, jax, np, days, want,
-                         runs=2, fused_backend="words-cpu")
+    _, c, name, _, _ = run_bmi("bmi-words", pum, realworld, jax, np, days,
+                               want, runs=2, fused_backend="words-cpu")
     lo, hi = flush_devices(c)
     if lo < 1:
         fail(f"bmi-words: {name} computed a flush in host NumPy")
@@ -199,15 +209,23 @@ def four_chips(pum, realworld, jax, np, seed: int) -> None:
         fail(f"--chips 4 needs four devices, JAX sees {n_dev}")
     rng = np.random.default_rng(seed)
     days, want = bmi_data(rng, np)
-    got_sh, c, _ = run_bmi("bmi-shard", pum, realworld, jax, np, days, want,
-                           runs=2, fused_backend="shard-words")
+    got_sh, c, _, placed, leaf_devices = run_bmi(
+        "bmi-shard", pum, realworld, jax, np, days, want, runs=2,
+        fused_backend="shard-words")
     lo, hi = flush_devices(c)
     if lo != 4 or hi != 4:
         fail(f"bmi-shard: outputs split across {lo}..{hi} devices, not 4")
+    if len(leaf_devices) != DAYS or set(leaf_devices) != {4}:
+        fail(f"bmi-shard: committed leaves span {sorted(set(leaf_devices))}"
+             f" devices ({len(leaf_devices)} of {DAYS} committed), not 4")
+    if placed[1] != 0:
+        fail(f"bmi-shard: the second run placed {placed[1]} leaf bytes; "
+             f"the leaves should stay resident")
     log("bmi-shard", phase_stats(c)
-        + f" output shards on {hi} distinct devices")
-    got_p, c, name = run_bmi("bmi-pallas", pum, realworld, jax, np, days,
-                             want, runs=1)
+        + f" output shards on {hi} distinct devices, {DAYS} leaves "
+          f"resident on 4 devices, leaf bytes placed by run {placed}")
+    got_p, c, name, _, _ = run_bmi("bmi-pallas", pum, realworld, jax, np,
+                                   days, want, runs=1)
     if name != "pallas-tpu":
         fail(f"bmi-pallas: select_backend picked {name!r}")
     if got_p != got_sh:

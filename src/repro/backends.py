@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any, Callable
 
 
@@ -286,6 +287,35 @@ def multi_device() -> bool:
     return len(jax.devices()) > 1
 
 
+# What the one-chip ``pallas-tpu`` plan of a flush takes per byte of its
+# leaves: the leaves, their bit-plane stack and the outputs. The TPU
+# compiler plans 8.25 GiB for the whole-table bitmap query's 3.75 GiB of
+# leaves (2.2x) and 16.25 GiB for 7.5 GiB (2.17x); 2.25 is the larger
+# ratio rounded up to a quarter (chipbench/tests/test_chipbench_fit.py).
+ONE_CHIP_PLAN_FACTOR = 2.25
+
+
+def shards_over_devices(devices: int, leaf_bytes: int,
+                        chip_bytes: int | None) -> bool:
+    """The size rule of fused selection: a flush goes to ``shard-words``
+    when the host has more than one device and the one-chip plan of its
+    leaves (``leaf_bytes`` x :data:`ONE_CHIP_PLAN_FACTOR`) exceeds one
+    device's memory limit ``chip_bytes``. A host whose devices report no
+    limit (the CPU) keeps the priority choice."""
+    return (devices > 1 and chip_bytes is not None
+            and leaf_bytes * ONE_CHIP_PLAN_FACTOR > chip_bytes)
+
+
+@functools.lru_cache(maxsize=1)
+def device_memory() -> tuple[int, int | None]:
+    """(local device count, the first device's ``bytes_limit``, or None
+    where the backend reports no memory statistics)."""
+    import jax
+    devices = jax.devices()
+    stats = devices[0].memory_stats() or {}
+    return len(devices), stats.get("bytes_limit")
+
+
 register_backend("fast", _build_fast_dataplane,
                  capabilities=("eager",), max_width=64, priority=10,
                  layouts=(32, 64))
@@ -318,9 +348,11 @@ register_backend("ref-vertical-64", _build_ref_vertical_pipeline,
                  priority=-10, available=lambda: False, layouts=(64,))
 
 # Multi-device sharded word pipeline: partitions the program's word axis
-# across jax.devices() (jax.sharding mesh placement). Auto-selected only
-# on multi-device hosts (beats words-cpu, loses to single-chip Pallas);
-# always requestable by name (EngineConfig.fused_backend="shard-words").
+# across jax.devices() (jax.sharding mesh placement). On a multi-device
+# host it beats words-cpu by priority and loses to single-chip Pallas,
+# except where the flush's leaves outgrow one chip: get_pipeline then
+# takes it by the size rule (shards_over_devices). Always requestable by
+# name (EngineConfig.fused_backend="shard-words").
 register_backend("shard-words", _build_sharded_words_pipeline,
                  capabilities=("fused", "sharded"), max_width=32,
                  priority=15, available=multi_device)

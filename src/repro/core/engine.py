@@ -342,17 +342,19 @@ def _stage_wire(flat, pad: int, layout: PlaneLayout,
 
 class _LeafCacheEntry:
     """One cached leaf upload: the private padded host wire plus (lazily)
-    its committed device buffer. ``fp`` is the 257-sample content
-    fingerprint taken when the source buffer was registered — a lookup
-    only hits while the caller's memory still matches it."""
+    its committed device buffer and the placement it was committed under.
+    ``fp`` is the 257-sample content fingerprint taken when the source
+    buffer was registered — a lookup only hits while the caller's memory
+    still matches it."""
 
-    __slots__ = ("key", "fp", "wire", "dev", "nbytes")
+    __slots__ = ("key", "fp", "wire", "dev", "placement", "nbytes")
 
     def __init__(self, key, fp, wire):
         self.key = key
         self.fp = fp
         self.wire = wire        # private padded int32 host wire
         self.dev = None         # committed jax buffer (lazy, non-donating)
+        self.placement = None   # a pipeline's placement; None: one device
         self.nbytes = wire.nbytes
 
 
@@ -418,24 +420,31 @@ class _LeafCache:
                 evicted += 1
         return entry, evicted
 
-    def device_buffer(self, entry: "_LeafCacheEntry"):
-        """The entry's committed device buffer (uploads once, lazily)."""
+    def device_buffer(self, entry: "_LeafCacheEntry", placement=None):
+        """The entry's buffer committed under ``placement`` (a pipeline's
+        ``placement``; ``None`` is JAX's default device), and the bytes
+        this call moved to commit it: it commits once and is kept across
+        flushes, and a buffer committed under another placement is
+        committed again."""
         dev = entry.dev
-        if dev is None:
+        if dev is not None and entry.placement == placement:
+            return dev, 0
+        if placement is None:
             import jax.numpy as jnp
             dev = jnp.asarray(entry.wire)
-            with self._lock:
-                if entry.dev is None:
-                    entry.dev = dev
-                else:       # another flush won the commit race
-                    dev = entry.dev
-        return dev
+        else:
+            dev = placement.put(entry.wire)
+        with self._lock:
+            if entry.dev is not None and entry.placement == placement:
+                return entry.dev, 0     # another flush won the commit race
+            entry.dev, entry.placement = dev, placement
+        return dev, dev.nbytes
 
     def drop_device(self, entry: "_LeafCacheEntry") -> None:
         """Release device residency (donating flushes: the trace consumes
         a fresh buffer, so any committed mirror is stale weight)."""
         with self._lock:
-            entry.dev = None
+            entry.dev = entry.placement = None
 
     def clear(self) -> None:
         with self._lock:
@@ -446,14 +455,15 @@ class _LeafCache:
 class _Leaf:
     """One registered operand of an op graph.
 
-    Exactly one staging source is set:
-
-    * ``entry`` — a leaf-cache entry whose fingerprint matched at record
-      time: flush stages the cached wire (or its committed device
+    * ``entry`` alone — a leaf-cache entry whose fingerprint matched at
+      record time: flush stages the cached wire (or its committed device
       buffer) and the record-time ``.copy()`` is elided entirely;
     * ``wire`` — the record-time snapshot, already in padded wire form
       (one fused pad+convert copy when the operand aliased caller
-      memory; a zero-copy view when ``ravel()`` already privatized it).
+      memory; a zero-copy view when ``ravel()`` already privatized it),
+      staged by this flush. ``entry`` is set beside it when the snapshot
+      seeded the leaf cache: the flush then commits the entry, so the
+      leaf is resident from its first flush on.
     """
 
     __slots__ = ("wire", "entry", "nbytes")
@@ -587,10 +597,10 @@ class _OpGraph:
             wire = _stage_wire(flat, self._pad, self.layout, copy=shared)
             if wire.base is not None and not shared:
                 self.elided_bytes += nbytes      # staged as a pure view
-            if ckey is not None:                 # seed for the next flush
+            if ckey is not None:                 # seed the cache
                 entry, ev = self.cache.insert(ckey, fp, wire)
                 self.cache_evictions += ev
-            self.leaves.append(_Leaf(wire=wire, entry=None, nbytes=nbytes))
+            self.leaves.append(_Leaf(wire=wire, entry=entry, nbytes=nbytes))
         self._fps.append(fp)
         # Pin the original: the id() dedup key is only valid while the
         # caller's array stays alive.
@@ -601,8 +611,7 @@ class _OpGraph:
         """The padded int32 host wire for leaf ``li`` (zero-copy: either
         the record-time snapshot or the cached upload's host wire)."""
         leaf = self.leaves[li]
-        e = leaf.entry
-        return leaf.wire if e is None else e.wire
+        return leaf.entry.wire if leaf.wire is None else leaf.wire
 
     def add_op(self, opcode: str, args: tuple, param: int,
                out: "LazyArray", internal: bool = False) -> int:
@@ -1474,13 +1483,13 @@ class PulsarEngine:
             leaves = []
             for li in leaf_map:
                 leaf = g.leaves[li]
-                if leaf.entry is not None:
+                if leaf.wire is None:
                     hits += 1
                     skipped_b += leaf.entry.nbytes
-                    leaves.append(leaf.entry)
                 else:
                     staged_b += leaf.wire.nbytes
-                    leaves.append(leaf.wire)
+                leaves.append(leaf.wire if leaf.entry is None
+                              else leaf.entry)
             if self.tracer is not None:
                 sp_up.args["bytes_staged"] = staged_b
                 sp_up.args["bytes_skipped"] = skipped_b
@@ -1507,15 +1516,22 @@ class PulsarEngine:
         with tr.span("flush.compile", flush=fid) as sp_c:
             if self.tracer is not None:
                 misses0 = _fused._cached_pipeline.cache_info().misses
-            pipeline = get_pipeline(program, donate=self.donate_leaves,
-                                    backend=self.fused_backend)
+            pipeline = get_pipeline(
+                program, donate=self.donate_leaves,
+                backend=self.fused_backend,
+                leaf_bytes=sum(x.nbytes for x in leaves))
             if self.tracer is not None:
                 hit = (_fused._cached_pipeline.cache_info().misses
                        == misses0)
                 self.counters.inc("engine.pipeline_cache.hit" if hit
                                   else "engine.pipeline_cache.miss")
                 sp_c.args["cache"] = "hit" if hit else "miss"
-        leaves = self._resolve_cached_leaves(g, pipeline, leaves)
+        with tr.span("flush.place", flush=fid) as sp_p:
+            leaves, placed, devices = self._resolve_cached_leaves(
+                g, pipeline, leaves)
+            if self.tracer is not None:
+                sp_p.args.update(bytes=placed, devices=devices)
+                self.counters.inc("engine.leaf_bytes_placed", placed)
         rel = self.reliability
         with tr.span("flush.dispatch", flush=fid, n_ops=len(program.ops),
                      n_lanes=g.n) as sp_d:
@@ -1567,37 +1583,49 @@ class PulsarEngine:
             # its side — a re-tune's own flushes never recurse).
             self.autotuner.on_flush(self)
 
-    def _resolve_cached_leaves(self, g: _OpGraph, pipeline, leaves) -> list:
-        """Resolve staged leaf-cache entries against the compiled pipeline:
+    def _resolve_cached_leaves(self, g: _OpGraph, pipeline,
+                               leaves) -> tuple[list, int, int]:
+        """Resolve staged leaf-cache entries against the compiled pipeline;
+        returns the leaves to call it with, the bytes of them that cross
+        to a device for this flush, and the devices they land on:
 
-        * non-donating jitted pipelines (``pipeline.wants_device`` says
-          the program is big enough to leave the NumPy short-circuit)
-          get the entry's committed device buffer — repeat flushes
-          re-upload nothing;
+        * a pipeline that runs on the device at this size
+          (``pipeline.wants_device``) and does not donate gets each
+          entry's buffer committed under the pipeline's ``placement``
+          (JAX's default device where it states none): committed once
+          and kept, so repeat flushes re-upload nothing;
         * everything else gets the entry's private host wire; a donating
           flush additionally drops the entry's device residency (the
           trace device-puts and donates a FRESH buffer — cached buffers
           are never donated, donated ones are never cached).
+
+        Host wire handed to a pipeline that runs on the device crosses
+        inside the call, and counts as placed too.
         """
-        if not any(isinstance(x, _LeafCacheEntry) for x in leaves):
-            return leaves
         cache = self._leaf_cache
         wants = getattr(pipeline, "wants_device", None)
         wire_words = (g.n + g._pad) * g.layout.wire_words_per_lane
-        use_dev = (not self.donate_leaves and wants is not None
-                   and wants(wire_words))
+        on_dev = wants is not None and wants(wire_words)
+        use_dev = on_dev and not self.donate_leaves
+        placement = getattr(pipeline, "placement", None)
+        placed = 0
         out = []
         for x in leaves:
             if isinstance(x, _LeafCacheEntry):
                 if use_dev:
-                    out.append(cache.device_buffer(x))
-                else:
-                    if self.donate_leaves:
-                        cache.drop_device(x)
-                    out.append(x.wire)
-            else:
-                out.append(x)
-        return out
+                    buf, moved = cache.device_buffer(x, placement)
+                    placed += moved
+                    out.append(buf)
+                    continue
+                if self.donate_leaves:
+                    cache.drop_device(x)
+                x = x.wire
+            if on_dev:
+                placed += x.nbytes
+            out.append(x)
+        devices = 0 if not on_dev else (
+            1 if placement is None else placement.devices)
+        return out, placed, devices
 
     _PLANEWISE = frozenset({"and", "or", "xor"})
 

@@ -18,6 +18,7 @@ Optimizer states mirror their parameters (same tree structure).
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any
 
 import jax
@@ -244,3 +245,39 @@ def words_mesh(devices=None) -> Mesh:
 def words_sharding(mesh: Mesh) -> NamedSharding:
     """Leading-axis sharding of a flat packed-word array over ``mesh``."""
     return NamedSharding(mesh, P("words"))
+
+
+@dataclasses.dataclass(frozen=True)
+class WordsPlacement:
+    """Where the ``shard-words`` pipeline keeps a leaf: contiguous word
+    ranges over the ``("words",)`` mesh, the leaf padded with zero words
+    to ``multiple`` (32 words a device, so every shard is whole lane
+    groups). The leaf cache commits its entries through :meth:`put`, and
+    the pipeline places host leaves the same way, so both agree on one
+    placement."""
+    sharding: NamedSharding
+    multiple: int
+
+    @property
+    def devices(self) -> int:
+        return self.sharding.mesh.size
+
+    def holds(self, x) -> bool:
+        """``x`` is already committed under this placement."""
+        return isinstance(x, jax.Array) and x.sharding == self.sharding
+
+    def put(self, wire) -> jax.Array:
+        """Commit one flat wire array: pad it (one host copy, only when
+        its length is not a multiple), then ``device_put`` each device's
+        contiguous slice."""
+        pad = (-wire.shape[0]) % self.multiple
+        if pad:
+            wire = np.pad(np.asarray(wire), (0, pad))
+        return jax.device_put(wire, self.sharding)
+
+
+def words_placement(devices=None) -> WordsPlacement:
+    """The placement of ``shard-words`` over ``devices`` (default: every
+    local device)."""
+    sharding = words_sharding(words_mesh(devices))
+    return WordsPlacement(sharding, 32 * sharding.mesh.size)

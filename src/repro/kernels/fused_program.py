@@ -97,7 +97,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.backends import get_backend, select_backend
+from repro.backends import (device_memory, get_backend, select_backend,
+                            shards_over_devices)
 from repro.kernels import ref
 from repro.kernels.bit_transpose import bit_transpose32 as _pl_transpose
 from repro.kernels.plane_layout import LAYOUT32, PlaneLayout
@@ -708,7 +709,8 @@ def run_program_pallas(program: FusedProgram, x: jax.Array,
 
 def get_pipeline(program: FusedProgram, force_pallas: bool = False,
                  interpret: bool = False, force_vertical: bool = False,
-                 donate: bool = False, backend: str | None = None):
+                 donate: bool = False, backend: str | None = None,
+                 leaf_bytes: int = 0):
     """Compiled callable for ``program``: ``fn(*leaves) -> tuple(outs)``.
 
     Leaves are flat int32 *wire* arrays of packed horizontal words
@@ -721,12 +723,15 @@ def get_pipeline(program: FusedProgram, force_pallas: bool = False,
     outputs transpose back once); elsewhere the word-domain evaluator
     runs. ``backend=`` names a registered evaluator explicitly;
     ``force_pallas``/``force_vertical`` are shorthands for the built-in
-    names at the program's layout. With ``donate=True`` the leaf
-    device buffers are donated to the trace (``donate_argnums``) so XLA
-    may reuse them for intermediates — the engine's leaf snapshots stay on
-    the host, so donation never invalidates caller-visible data. Cached
-    on (program structure, backend, donate); jit handles per-shape
-    specialization.
+    names at the program's layout. ``leaf_bytes`` (the flush's operand
+    bytes) puts an unnamed choice to the size rule
+    (:func:`repro.backends.shards_over_devices`): leaves that outgrow one
+    chip of a multi-device host go to ``shard-words``. With
+    ``donate=True`` the leaf device buffers are donated to the trace
+    (``donate_argnums``) so XLA may reuse them for intermediates — the
+    engine's leaf snapshots stay on the host, so donation never
+    invalidates caller-visible data. Cached on (program structure,
+    backend, donate); jit handles per-shape specialization.
     """
     wb = program.layout.word_bits
     if backend is None:
@@ -737,6 +742,10 @@ def get_pipeline(program: FusedProgram, force_pallas: bool = False,
         else:
             backend = select_backend(require="fused", width=program.width,
                                      layout=program.layout).name
+            if leaf_bytes and wb == 32 and backend != "shard-words":
+                devices, chip_bytes = device_memory()
+                if shards_over_devices(devices, leaf_bytes, chip_bytes):
+                    backend = "shard-words"
     spec = get_backend(backend)
     if wb not in spec.layouts:
         raise ValueError(
@@ -825,13 +834,7 @@ def build_words_pipeline(program: FusedProgram, donate: bool = False):
         op.opcode in ("div", "mod", "divmod") for op in program.ops)
 
     if layout.word_bits == 32:
-        def core(*leaves):
-            outs = run_program_words(
-                program,
-                [jax.lax.bitcast_convert_type(x, jnp.uint32)
-                 for x in leaves])
-            return tuple(jax.lax.bitcast_convert_type(o, jnp.int32)
-                         for o in outs)
+        core = words_fn(program)
     else:
         def core(*leaves):
             return run_program_pairs(program, leaves)
@@ -855,13 +858,27 @@ def build_words_pipeline(program: FusedProgram, donate: bool = False):
             return np_words(*leaves)
         return jitted(*leaves)
 
-    # Leaf-cache protocol (engine._resolve_cached_leaves): cached device
-    # buffers are only worth serving when the call will actually run
-    # jitted — and never into a donating trace.
+    # Leaf-cache protocol (engine._resolve_cached_leaves): the call runs
+    # jitted on the device at this size, so its leaves cross to it and
+    # committed buffers are worth serving (the engine never serves them
+    # to a donating trace).
     word_pipeline.wants_device = (
-        lambda wire_words: not donate and not np_div64
-        and not small(wire_words))
+        lambda wire_words: not np_div64 and not small(wire_words))
     return word_pipeline
+
+
+def words_fn(program: FusedProgram):
+    """The 32-bit word-domain program as a traceable function of int32
+    wire leaves: what the ``words-cpu`` and ``shard-words`` pipelines
+    jit."""
+    def core(*leaves):
+        outs = run_program_words(
+            program,
+            [jax.lax.bitcast_convert_type(x, jnp.uint32) for x in leaves])
+        return tuple(jax.lax.bitcast_convert_type(o, jnp.int32)
+                     for o in outs)
+
+    return core
 
 
 def build_sharded_words_pipeline(program: FusedProgram,
@@ -873,38 +890,30 @@ def build_sharded_words_pipeline(program: FusedProgram,
     communication-free — GSPMD places each shard's slice of the fused
     elementwise DAG on its device; outputs gather on read-back.
 
-    Leaves pad to a multiple of 32 x n_devices before placement. Outputs
-    stay on the devices: sharded when no padding was needed, otherwise
-    sliced back to the leaves' length on the device. ``donate`` is
+    The pipeline states its placement (``.placement``, a
+    :class:`~repro.distributed.sharding.WordsPlacement`): leaves already
+    committed under it run as they are (the leaf cache keeps them so),
+    host leaves are padded to a multiple of 32 x n_devices and placed on
+    each call. Outputs stay sharded on the devices, at the placed
+    length; the caller reads its lanes from the front. ``donate`` is
     ignored: donated input buffers would alias the per-device shards the
     caller still owns.
     """
-    from repro.distributed.sharding import words_mesh, words_sharding
+    from repro.distributed.sharding import words_placement
 
     if program.layout.word_bits != 32:
         raise ValueError("shard-words shards the 32-bit word layout; "
                          "register a 64-bit variant to widen it")
-    sharding = words_sharding(words_mesh())
-    n_dev = sharding.mesh.size
-
-    def word_pipeline(*leaves):
-        outs = run_program_words(
-            program,
-            [jax.lax.bitcast_convert_type(x, jnp.uint32)
-             for x in leaves])
-        return tuple(jax.lax.bitcast_convert_type(o, jnp.int32)
-                     for o in outs)
-
-    jitted = jax.jit(word_pipeline)
+    placement = words_placement()
+    jitted = jax.jit(words_fn(program))
 
     def sharded_pipeline(*leaves):
-        n = np.asarray(leaves[0]).shape[0]
-        pad = (-n) % (32 * n_dev)
-        placed = [jax.device_put(np.pad(np.asarray(x, np.int32), (0, pad)),
-                                 sharding) for x in leaves]
-        outs = jitted(*placed)
-        return outs if not pad else tuple(o[:n] for o in outs)
+        return jitted(*(x if placement.holds(x) else placement.put(x)
+                        for x in leaves))
 
+    sharded_pipeline.placement = placement
+    # Leaf-cache protocol: every call runs on the devices.
+    sharded_pipeline.wants_device = lambda wire_words: True
     return sharded_pipeline
 
 
@@ -945,7 +954,6 @@ def build_vertical_pipeline(program: FusedProgram, use_pallas: bool,
     def vertical_pipeline(*leaves):
         return fn(*leaves)
 
-    # Leaf-cache protocol: the vertical path is always jitted, so cached
-    # device buffers are always worth serving (unless donating).
-    vertical_pipeline.wants_device = lambda wire_words: not donate
+    # Leaf-cache protocol: the vertical path always runs on the device.
+    vertical_pipeline.wants_device = lambda wire_words: True
     return vertical_pipeline

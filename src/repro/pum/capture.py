@@ -255,6 +255,7 @@ class CapturedProgram:
         # safe to serve whenever the pipeline runs jitted.
         use_dev = cache is not None and wants is not None and wants(
             (rec.n + rec.pad) * rec.layout.wire_words_per_lane)
+        placement = getattr(rec.pipeline, "placement", None)
         hits = misses = 0
         leaves = []
         for kind, v in rec.plan:
@@ -291,8 +292,8 @@ class CapturedProgram:
                     continue
             else:
                 hits += 1
-            leaves.append(cache.device_buffer(entry) if use_dev
-                          else entry.wire)
+            leaves.append(cache.device_buffer(entry, placement)[0]
+                          if use_dev else entry.wire)
         if eng.tracer is not None and (hits or misses):
             if hits:
                 eng.counters.inc("engine.leaf_cache.hits", hits)

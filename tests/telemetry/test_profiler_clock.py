@@ -22,8 +22,9 @@ from repro.telemetry import compiles, process_counters
 pytestmark = pytest.mark.fused
 
 FLUSH_SPANS = ["flush.record", "flush.optimize", "flush.leaf_upload",
-               "flush.compile", "flush.dispatch", "flush.materialize",
-               "flush.wait", "flush.fetch", "flush.unpack"]
+               "flush.compile", "flush.place", "flush.dispatch",
+               "flush.materialize", "flush.wait", "flush.fetch",
+               "flush.unpack"]
 CHILDREN = ["flush.wait", "flush.fetch", "flush.unpack"]
 
 
