@@ -247,10 +247,18 @@ class LazyArray:
     def __array__(self, dtype=None, copy=None):
         return _as_array(self.materialize(), dtype, copy)
 
+    def sum(self, *args, **kw):
+        """The total of the lanes. With no argument, while pending: a
+        :class:`LazySum` the flush may compute on the device
+        (``PulsarEngine._sum``); otherwise the host's NumPy sum."""
+        eng = self._engine
+        parts = None if eng is None else eng._sum(self, *args, **kw)
+        if parts is None:
+            return self.materialize().sum(*args, **kw)
+        return LazySum(parts)
+
     # ndarray conveniences the app kernels lean on: each materializes
     # (flushing the graph) and delegates — results are plain ndarrays.
-    def sum(self, *args, **kw):
-        return self.materialize().sum(*args, **kw)
 
     def reshape(self, *shape, **kw) -> np.ndarray:
         return self.materialize().reshape(*shape, **kw)
@@ -276,6 +284,69 @@ class LazyArray:
         return f"LazyArray(shape={self.shape}, {state})"
 
 
+class LazySum:
+    """The total of a pending value's lanes: what ``sum()`` of a pending
+    value on a fused device returns (``PulsarEngine._sum``). It holds the
+    value's partial sums, pending in the same graph: a few uint32
+    partials summed on the device, or the lanes themselves where the
+    flush leaves the sum to the host. Reading it in any way reads them
+    (flushing the graph) and adds them up in ``uint64``: the host sum's
+    number, an ``np.uint64``, which it then behaves as."""
+
+    __slots__ = ("_parts", "_value")
+    shape = ()
+
+    def __init__(self, parts):
+        self._parts = parts  # a pending LazyArray, or a PumArray of one
+        self._value: np.uint64 | None = None
+
+    def materialize(self) -> np.uint64:
+        if self._value is None:
+            self._value = np.asarray(self._parts, np.uint64).sum(
+                dtype=np.uint64)
+            self._parts = None
+        return self._value
+
+    def __array__(self, dtype=None, copy=None):
+        return _as_array(np.asarray(self.materialize()), dtype, copy)
+
+    def __int__(self):
+        return int(self.materialize())
+
+    def __index__(self):
+        return int(self.materialize())
+
+    def __float__(self):
+        return float(self.materialize())
+
+    def __bool__(self):
+        return bool(self.materialize())
+
+    def __hash__(self):
+        return hash(self.materialize())
+
+    def __repr__(self) -> str:
+        if self._value is None:
+            return "LazySum(pending)"
+        return f"LazySum({int(self._value)})"
+
+
+def _lazy_sum_operator(name: str):
+    def op(self, *other):
+        return getattr(self.materialize(), name)(*other)
+    op.__name__ = name
+    return op
+
+
+for _name in ("__eq__", "__ne__", "__lt__", "__le__", "__gt__", "__ge__",
+              "__add__", "__radd__", "__sub__", "__rsub__", "__mul__",
+              "__rmul__", "__floordiv__", "__rfloordiv__", "__mod__",
+              "__rmod__", "__truediv__", "__rtruediv__", "__divmod__",
+              "__rdivmod__", "__pow__", "__rpow__", "__neg__", "__pos__",
+              "__abs__"):
+    setattr(LazySum, _name, _lazy_sum_operator(_name))
+
+
 def _as_array(v: np.ndarray, dtype, copy) -> np.ndarray:
     """``v`` under NumPy's ``__array__(dtype, copy)`` protocol: ``v``
     itself when it already has ``dtype`` and no copy is asked for."""
@@ -287,10 +358,13 @@ def _as_array(v: np.ndarray, dtype, copy) -> np.ndarray:
 
 
 def _unpack_output(layout: PlaneLayout, wire: np.ndarray, n: int,
-                   raw: bool, popcount: bool) -> np.ndarray:
+                   raw: bool, popcount: bool, reduced: bool = False):
     """One fetched flush output -> the caller's flat ``uint64`` value, in
     one host pass into one fresh buffer (the fetched wire is read-only and
-    owned by JAX; callers own what they get)."""
+    owned by JAX; callers own what they get). A reduced output's wire is
+    its uint32 partial sums, which widen to ``uint64``."""
+    if reduced:
+        return wire.astype(np.uint64)
     lanes = layout.from_wire(wire)[:n]
     if not raw:
         return lanes.astype(np.uint64)
@@ -520,6 +594,7 @@ class _OpGraph:
         self.cache_evictions = 0
         self.ops: list[tuple[str, tuple, int]] = []  # (opcode, args, param)
         self.results: list = []         # weakref per op
+        self.sums: list = []  # (op index, weakref to its partial sums)
         # Set only while a tracer is attached: the engine's sequence
         # number of this flush (every flush.* span carries it), and the
         # "flush.record" span, open from the first recorded op until the
@@ -1201,6 +1276,35 @@ class PulsarEngine:
             self.flush()  # auto-flush: `out` is live, materializes
         return out
 
+    def _sum(self, x: LazyArray, *args, **kw) -> "LazyArray | None":
+        """Where ``x.sum(*args, **kw)`` runs. With no argument, while
+        ``x`` is pending, where its op bounds its lanes (``lane_bound``),
+        on the 32-bit layout, under 2^31 lanes (the pipeline counts lanes
+        in int32) and with no fault injection (it votes on output lanes,
+        so a sum must follow the vote): the pending partial sums of ``x``
+        (what a :class:`LazySum` adds up), which the flush reduces on the
+        device, so only they cross the link. None where the caller sums
+        on the host, as before."""
+        if x._value is not None:
+            return None
+        g = x._graph
+        rel = self.reliability
+        with self._lock:
+            if not args and not kw and g is not None \
+                    and g.state == "recording" \
+                    and g.layout.word_bits == 32 \
+                    and g.n + g._pad < 1 << 31 \
+                    and _fused.lane_bound(g.ops[x._op_idx][0],
+                                          g.width) is not None \
+                    and (rel is None or not rel.inject):
+                # Its shape is the partials', known once the flush ran.
+                parts = LazyArray(self, g, x._op_idx, (0,))
+                g.sums.append((x._op_idx, weakref.ref(parts)))
+                return parts
+        if self.tracer is not None:
+            self.counters.inc("engine.sums.host")
+        return None
+
     def _graph_over_threshold(self, g: _OpGraph) -> str | None:
         """Auto-flush policy: graph-size (recorded ops), estimated
         memory (one layout word per lane per held value: leaf snapshots
@@ -1456,8 +1560,16 @@ class PulsarEngine:
         # died unreferenced are dead code (their cost was still charged,
         # as in eager mode, but no dataplane work remains).
         out_idx = [i for i, lz in enumerate(live) if lz is not None]
-        if not out_idx:
+        sums = [(i, s) for i, wr in g.sums if (s := wr()) is not None]
+        if not out_idx and not sums:
             return None
+        # An op only summed is a reduced output: its partial sums cross,
+        # not its lanes. Lanes asked for anyway are summed on the host,
+        # and so is every sum under fault injection, after the vote.
+        summed = sorted({i for i, _ in sums}.difference(out_idx))
+        outputs = out_idx + summed
+        rel = self.reliability
+        reduced = () if rel is not None and rel.inject else summed
         n_leaves = len(g.leaves)
 
         def vid(tag):  # combined id space: leaves first, then ops
@@ -1470,8 +1582,9 @@ class PulsarEngine:
                 ops=tuple(FusedOp(opcode, tuple(vid(a) for a in args),
                                   param)
                           for opcode, args, param in g.ops),
-                outputs=tuple(n_leaves + i for i in out_idx),
-                layout=g.layout)
+                outputs=tuple(n_leaves + i for i in outputs),
+                layout=g.layout,
+                reduced=tuple(n_leaves + i for i in reduced))
             program, out_pos, leaf_map = optimize_program(program)
             sp_opt.args["n_ops_out"] = len(program.ops)
         with tr.span("flush.leaf_upload", flush=fid,
@@ -1506,11 +1619,11 @@ class PulsarEngine:
                     g.elided_bytes = 0
                 if staged_b:
                     c.inc("engine.leaf_bytes_staged", staged_b)
-        return (program, out_pos, live, out_idx, leaves)
+        return (program, out_pos, live, outputs, sums, leaves)
 
     def _run_staged(self, g: _OpGraph, staged) -> None:
         """Dispatch-side half of a flush: compile, run, materialize."""
-        program, out_pos, live, out_idx, leaves = staged
+        program, out_pos, live, outputs, sums, leaves = staged
         tr = NULL_TRACER if self.tracer is None else self.tracer
         fid = g.flush_id
         with tr.span("flush.compile", flush=fid) as sp_c:
@@ -1544,10 +1657,12 @@ class PulsarEngine:
                     pipeline,
                     lambda o: rel.correct(o, program, g.n, span=sp_d))
                 outs = voted(*leaves)
+            elif program.reduced:
+                outs = pipeline(*leaves, lanes=g.n)
             else:
                 outs = pipeline(*leaves)
         with tr.span("flush.materialize", flush=fid,
-                     n_outputs=len(out_idx)):
+                     n_outputs=len(outputs)):
             with tr.span("flush.wait", flush=fid):
                 # The host blocks on the device (host outputs are ready).
                 jax.block_until_ready(outs)
@@ -1557,21 +1672,41 @@ class PulsarEngine:
                     sp_f.args["bytes"] = sum(a.nbytes
                                              for a in fetched.values())
             with tr.span("flush.unpack", flush=fid) as sp_u:
-                for i, pos in zip(out_idx, out_pos):
-                    lz = live[i]
+                on_device = {i for i, pos in zip(outputs, out_pos)
+                             if program.outputs[pos] in program.reduced}
+                values = {}
+                for i, pos in zip(outputs, out_pos):
                     val = _unpack_output(g.layout, fetched[pos], g.n, g.raw,
-                                         g.ops[i][0] == "popcount")
-                    lz._value = val.reshape(lz.shape)
-                    # A materialized handle never needs the graph again —
-                    # drop the references so surviving handles don't pin
-                    # the leaf snapshots (or the engine) for their
-                    # lifetime.
-                    lz._graph = None
-                    lz._engine = None
+                                         g.ops[i][0] == "popcount",
+                                         reduced=i in on_device)
+                    lz = live[i]
+                    if lz is not None:
+                        val = lz._value = val.reshape(lz.shape)
+                    values[i] = val
+                # A summed op's partials: the device's, or else the host's
+                # sum of the lanes that crossed anyway, taken now (a live
+                # handle's caller owns those lanes and may write them).
+                for i, parts in sums:
+                    v = values[i]
+                    parts._value = v if i in on_device \
+                        else np.array([v.sum(dtype=np.uint64)])
+                    parts.shape = parts._value.shape
+                # A materialized handle never needs the graph again —
+                # drop the references so surviving handles don't pin the
+                # leaf snapshots (or the engine) for their lifetime.
+                for h in [live[i] for i in outputs] + [p for _, p in sums]:
+                    if h is not None:
+                        h._graph = None
+                        h._engine = None
                 if self.tracer is not None:
-                    sp_u.args["bytes"] = sum(
-                        _buffer_nbytes(live[i]._value) for i in out_idx)
+                    sp_u.args["bytes"] = sum(_buffer_nbytes(v)
+                                             for v in values.values())
         if self.tracer is not None:
+            n_device = sum(i in on_device for i, _ in sums)
+            if n_device:
+                self.counters.inc("engine.sums.device", n_device)
+            if len(sums) - n_device:
+                self.counters.inc("engine.sums.host", len(sums) - n_device)
             self.counters.inc("engine.flushes")
             self.counters.observe("engine.flush_lanes", g.n)
             self.counters.observe("engine.flush_ops", len(program.ops))

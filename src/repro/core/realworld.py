@@ -44,6 +44,26 @@ def _timed(fn):
     return out, (time.perf_counter() - t0) * 1e3
 
 
+def _and_rows(dev: Device, rows):
+    """The AND of ``rows`` (packed uint64 bitmaps of one length) on
+    ``dev``, as its one handle: the intermediate ANDs are dead code in a
+    fused flush."""
+    acc = dev.asarray(rows[0])
+    for r in rows[1:]:
+        acc = acc & r
+    return acc
+
+
+def _count_and(dev: Device, rows) -> int:
+    """Set bits of the AND of ``rows``. The popcount over the 64-bit
+    words' planes (bit-serial adder tree) charges the same cost-plane row
+    on any device. On a fused device it joins the AND chain in one
+    program, and the flush sums the counts on the device: no handle to
+    the AND survives to the read, so the flush copies back a few partial
+    sums and none of the lanes."""
+    return int(_and_rows(dev, rows).popcount(width=64).sum())
+
+
 def bmi_active_users(dev: Device, daily_bitmaps: np.ndarray,
                      verify: bool = True) -> tuple[int, float, float]:
     """daily_bitmaps: [days, n_users/64] packed uint64. Query: how many users
@@ -61,14 +81,7 @@ def bmi_active_users(dev: Device, daily_bitmaps: np.ndarray,
 
     want, cpu_ms = _timed(cpu) if verify else (None, 0.0)
     dev.reset_stats()
-    acc = dev.asarray(daily_bitmaps[0])
-    for d in range(1, days):
-        acc = acc & daily_bitmaps[d]
-    # Popcount over the 64-bit words' planes (bit-serial adder tree) runs
-    # on-device — on a fused device it joins the AND chain in the single
-    # compiled pass — and charges the same cost-plane row either way; the
-    # host only sums the per-word counts.
-    got = int(acc.popcount(width=64).to_numpy().sum())
+    got = _count_and(dev, daily_bitmaps)
     if verify:
         assert got == want
     return got, dev.latency_ms, cpu_ms
@@ -203,18 +216,9 @@ def kclique_star(dev: Device, adj_bits: np.ndarray,
     want, cpu_ms = _timed(cpu) if verify else (None, 0.0)
     dev.reset_stats()
     if stacks is not None:
-        acc = dev.asarray(stacks[0])
-        for s in stacks[1:]:
-            acc = acc & s
-        got = int(acc.popcount(width=64).to_numpy().sum())
+        got = _count_and(dev, stacks)
     else:
-        tot = 0
-        for cl in cliques:
-            acc = dev.asarray(rows[cl[0]])
-            for v in cl[1:]:
-                acc = acc & rows[v]
-            tot += int(acc.popcount(width=64).to_numpy().sum())
-        got = tot
+        got = sum(_count_and(dev, [rows[v] for v in cl]) for cl in cliques)
     if verify:
         assert got == want
     return got, dev.latency_ms, cpu_ms

@@ -45,6 +45,10 @@ Programs are frozen/hashable, so compiled pipelines are cached on graph
 *structure*: re-recording the same op sequence over new batches reuses the
 trace (jax.jit additionally caches per operand shape).
 
+An output the caller only sums is *reduced* (``FusedProgram.reduced``):
+one post-stage inside the pipeline (``with_sums``) turns its lanes into
+a few uint32 partial sums, so a flush copies back words, not lanes.
+
 Value semantics: elements are unsigned, width-bit (everything is computed
 modulo 2**width — the vertical layout physically holds ``width`` planes).
 Opcodes: and/or/xor (plane-wise), add/sub (ripple carry/borrow),
@@ -89,6 +93,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import warnings
 
 import jax
@@ -135,12 +140,18 @@ class FusedProgram:
     computes modulo ``2**width``. ``layout`` names the lane word format
     the pipeline evaluates in (and is part of the cache key — the same
     op structure compiled at two layouts is two pipelines).
+
+    ``reduced`` lists the outputs the caller only sums: the pipeline
+    returns each as uint32 partial sums of its lanes (:func:`with_sums`)
+    instead of the lanes. Each must be an op whose opcode bounds its
+    lanes (:func:`lane_bound`), on the 32-bit layout.
     """
     width: int
     n_inputs: int
     ops: tuple[FusedOp, ...]
     outputs: tuple[int, ...]  # value ids to materialize
     layout: PlaneLayout = LAYOUT32
+    reduced: tuple[int, ...] = ()  # outputs returned as partial sums
 
 
 def optimize_program(program: FusedProgram
@@ -159,6 +170,10 @@ def optimize_program(program: FusedProgram
       requested outputs onto one computed value).
     * ``leaf_map`` — original leaf ids still used, in the order the
       optimized program expects its inputs.
+
+    An output stays ``reduced`` only while every request CSE maps onto
+    its value is a reduced one: lanes asked for anywhere come back as
+    lanes.
 
     The optimizer never changes values (CSE only unifies syntactically
     identical ops, whose results are equal by determinism) and never
@@ -201,6 +216,9 @@ def _optimize_cached(program: FusedProgram):
             table[key] = canon[vid] = vid
             kept.append((vid, FusedOp(op.opcode, args, op.param)))
     out_canon = [canon.get(v, v) for v in program.outputs]
+    reduced = {canon.get(v, v) for v in program.reduced} - {
+        canon.get(v, v) for v in program.outputs
+        if v not in program.reduced}
     # Narrow each divmod consumed by only one kind of selector into the
     # direct div / mod op: the engine lowers both ``//`` and ``%`` through
     # the shared tuple op, so a program using just one half would
@@ -244,7 +262,8 @@ def _optimize_cached(program: FusedProgram):
         out_pos.append(pos_of[rv])
     opt = FusedProgram(width=program.width, n_inputs=len(leaf_map),
                        ops=ops, outputs=tuple(outputs),
-                       layout=program.layout)
+                       layout=program.layout,
+                       reduced=tuple(sorted(remap[v] for v in reduced)))
     return opt, tuple(out_pos), leaf_map
 
 
@@ -702,6 +721,108 @@ def run_program_pallas(program: FusedProgram, x: jax.Array,
 
 
 # --------------------------------------------------------------------- #
+# Reduced outputs: an output the caller only sums comes back as a few
+# uint32 partial sums of its lanes, one post-stage shared by every
+# built-in pipeline.
+# --------------------------------------------------------------------- #
+
+
+def lane_bound(opcode: str, width: int) -> int | None:
+    """The largest value one lane of ``opcode``'s result can hold, where
+    the opcode alone bounds it: a ``popcount`` counts at most ``width``
+    bits, ``less`` and the ``reduce_*`` give 0 or 1. None for every other
+    opcode, whose lanes span the width."""
+    if opcode == "popcount":
+        return width
+    if opcode in ("less", "reduce_and", "reduce_or", "reduce_xor"):
+        return 1
+    return None
+
+
+def sum_partials(lanes: int, bound: int, devices: int = 1
+                 ) -> tuple[int, int]:
+    """How a reduced output of ``lanes`` lanes folds into uint32 partial
+    sums, as ``(blocks, columns)``: ``blocks`` contiguous runs of lanes,
+    a multiple of ``devices`` (each device sums its own), and within a
+    run, lane ``i`` adds into column ``i % columns``. Columns are 128, a
+    TPU vector row, so the sum is plain vector adds and fills no buffer,
+    where a device's lanes allow; else 64 or 32 (every pipeline's lane
+    count is a multiple of 32 a device). Blocks double from ``devices``
+    while the lanes of one partial times ``bound`` (the largest lane
+    value) would reach 2**32, so no partial can wrap.
+
+    >>> sum_partials(1 << 25, 32), sum_partials(1 << 27, 32, devices=4)
+    ((1, 128), (4, 128))
+    >>> sum_partials(1 << 34, 32)   # 2^27 lanes of 32 a partial would wrap
+    (2, 128)
+    """
+    columns = math.gcd(lanes // devices, 128)
+    blocks = devices
+    while lanes % (2 * blocks * columns) == 0 \
+            and lanes // (blocks * columns) * bound >= 1 << 32:
+        blocks *= 2
+    if lanes % (devices * 32) \
+            or lanes // (blocks * columns) * bound >= 1 << 32:
+        raise ValueError(
+            f"{lanes} lanes bounded by {bound} cannot split into uint32 "
+            f"partial sums over {devices} device(s)")
+    return blocks, columns
+
+
+def sum_lanes(wire, lanes, bound: int, devices: int = 1, xp=jnp):
+    """One 32-bit wire output -> its uint32 partial sums, laid out by
+    :func:`sum_partials` and flattened, counting only the first ``lanes``
+    lanes: a padding lane never counts, whatever the program made of it.
+    On a word-sharded output each device sums its own blocks."""
+    if xp is np:
+        words = np.asarray(wire).view(np.uint32)
+    else:
+        words = jax.lax.bitcast_convert_type(wire, jnp.uint32)
+    size = words.shape[0]
+    blocks, columns = sum_partials(size, bound, devices)
+    kept = xp.where(xp.arange(size) < lanes, words, xp.uint32(0))
+    return kept.reshape(blocks, -1, columns).sum(axis=1, dtype=xp.uint32) \
+        .reshape(-1)
+
+
+def with_sums(program: FusedProgram, core, xp=jnp, devices: int = 1,
+              sharding=None):
+    """``core(*leaves) -> outs`` with ``program``'s reduced outputs
+    summed (:func:`sum_lanes`): the result takes the flush's real lane
+    count as ``lanes=`` — traced, so one trace serves every count. With
+    a ``sharding`` (``shard-words``) the partials stay split over the
+    devices, each device's on it, and nothing crosses between them.
+    ``core`` itself when nothing is reduced, so such a pipeline is the
+    same trace as before."""
+    if not program.reduced:
+        return core
+    if program.layout.word_bits != 32:
+        raise ValueError("reduced outputs need the 32-bit plane layout")
+    sums = []
+    for t, vid in enumerate(program.outputs):
+        if vid not in program.reduced:
+            continue
+        op = program.ops[vid - program.n_inputs] \
+            if vid >= program.n_inputs else None
+        bound = None if op is None else lane_bound(op.opcode, program.width)
+        if bound is None:
+            raise ValueError(f"output {vid} is reduced, but no opcode "
+                             f"bounds its lanes")
+        sums.append((t, bound))
+
+    def summed(*leaves, lanes):
+        outs = list(core(*leaves))
+        for t, bound in sums:
+            with jax.named_scope("pum.sum"):
+                outs[t] = sum_lanes(outs[t], lanes, bound, devices, xp)
+            if sharding is not None:
+                outs[t] = jax.lax.with_sharding_constraint(outs[t], sharding)
+        return tuple(outs)
+
+    return summed
+
+
+# --------------------------------------------------------------------- #
 # End-to-end pipeline: pack -> run -> unpack, one jit trace, cached.
 # Evaluator chosen by capability lookup in the repro.backends registry.
 # --------------------------------------------------------------------- #
@@ -731,7 +852,10 @@ def get_pipeline(program: FusedProgram, force_pallas: bool = False,
     (``donate_argnums``) so XLA may reuse them for intermediates — the
     engine's leaf snapshots stay on the host, so donation never
     invalidates caller-visible data. Cached on (program structure,
-    backend, donate); jit handles per-shape specialization.
+    backend, donate); jit handles per-shape specialization. A program
+    with ``reduced`` outputs gives ``fn(*leaves, lanes=n)``, ``n`` the
+    real lane count, and returns those outputs as uint32 partial sums
+    (:func:`with_sums`).
     """
     wb = program.layout.word_bits
     if backend is None:
@@ -790,11 +914,11 @@ def _donating(fn, n_leaves: int):
     and silenced."""
     jitted = jax.jit(fn, donate_argnums=tuple(range(n_leaves)))
 
-    def call(*leaves):
+    def call(*leaves, **kw):
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            return jitted(*(jnp.asarray(x) for x in leaves))
+            return jitted(*(jnp.asarray(x) for x in leaves), **kw)
 
     return call
 
@@ -839,24 +963,27 @@ def build_words_pipeline(program: FusedProgram, donate: bool = False):
         def core(*leaves):
             return run_program_pairs(program, leaves)
 
+    core = with_sums(program, core)
     jitted = (_donating(core, program.n_inputs) if donate
               else jax.jit(core))
 
-    def np_words(*leaves):
+    def np_core(*leaves):
         outs = run_program_words(
             program, [layout.from_wire(np.asarray(x)) for x in leaves])
         return tuple(layout.to_wire(o) for o in outs)
 
+    np_words = with_sums(program, np_core, xp=np)
+
     def small(wire_words):
         return host_cpu and wire_words * n_ops <= _NP_CUTOFF_WIRE_OPS
 
-    def word_pipeline(*leaves):
+    def word_pipeline(*leaves, **kw):
         if np_div64:
-            return np_words(*leaves)
+            return np_words(*leaves, **kw)
         if leaves and small(leaves[0].size) \
                 and all(isinstance(x, np.ndarray) for x in leaves):
-            return np_words(*leaves)
-        return jitted(*leaves)
+            return np_words(*leaves, **kw)
+        return jitted(*leaves, **kw)
 
     # Leaf-cache protocol (engine._resolve_cached_leaves): the call runs
     # jitted on the device at this size, so its leaves cross to it and
@@ -895,7 +1022,9 @@ def build_sharded_words_pipeline(program: FusedProgram,
     committed under it run as they are (the leaf cache keeps them so),
     host leaves are padded to a multiple of 32 x n_devices and placed on
     each call. Outputs stay sharded on the devices, at the placed
-    length; the caller reads its lanes from the front. ``donate`` is
+    length; the caller reads its lanes from the front. A reduced output
+    is summed on each device into its own block of partials, so only a
+    few words a device are gathered on read-back. ``donate`` is
     ignored: donated input buffers would alias the per-device shards the
     caller still owns.
     """
@@ -905,11 +1034,13 @@ def build_sharded_words_pipeline(program: FusedProgram,
         raise ValueError("shard-words shards the 32-bit word layout; "
                          "register a 64-bit variant to widen it")
     placement = words_placement()
-    jitted = jax.jit(words_fn(program))
+    jitted = jax.jit(with_sums(program, words_fn(program),
+                               devices=placement.devices,
+                               sharding=placement.sharding))
 
-    def sharded_pipeline(*leaves):
+    def sharded_pipeline(*leaves, **kw):
         return jitted(*(x if placement.holds(x) else placement.put(x)
-                        for x in leaves))
+                        for x in leaves), **kw)
 
     sharded_pipeline.placement = placement
     # Leaf-cache protocol: every call runs on the devices.
@@ -948,11 +1079,12 @@ def build_vertical_pipeline(program: FusedProgram, use_pallas: bool,
             return tuple(layout.unpack_planes(outs[t], transpose, width)
                          for t in range(outs.shape[0]))
 
+    pipeline = with_sums(program, pipeline)
     fn = _donating(pipeline, program.n_inputs) if donate \
         else jax.jit(pipeline)
 
-    def vertical_pipeline(*leaves):
-        return fn(*leaves)
+    def vertical_pipeline(*leaves, **kw):
+        return fn(*leaves, **kw)
 
     # Leaf-cache protocol: the vertical path always runs on the device.
     vertical_pipeline.wants_device = lambda wire_words: True

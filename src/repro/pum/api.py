@@ -33,7 +33,7 @@ import warnings
 
 import numpy as np
 
-from repro.core.engine import LazyArray, _as_array
+from repro.core.engine import LazyArray, LazySum, _as_array
 from repro.pum.config import EngineConfig
 
 # Innermost active `with device(...)` last; module default built lazily.
@@ -473,6 +473,16 @@ class PumArray:
         return _as_array(self.to_numpy(), dtype, copy)
 
     def sum(self, *args, **kw):
+        """The total of the elements: the host's NumPy sum, except that
+        ``sum()`` of a pending value on a fused device returns a pending
+        0-d :class:`~repro.core.engine.LazySum`, which the flush computes
+        on the device where it can (``PulsarEngine._sum``). Reading it
+        gives the same ``np.uint64``; it reads the partial sums as an
+        array of this device (``to_numpy``)."""
+        if isinstance(self._data, LazyArray):
+            parts = self._device.engine._sum(self._data, *args, **kw)
+            if parts is not None:
+                return LazySum(PumArray(self._device, parts))
         return self.to_numpy().sum(*args, **kw)
 
     def reshape(self, *shape, **kw) -> np.ndarray:

@@ -27,7 +27,7 @@ WORDS = 1 << 16  # words per plane of each compiled program
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -41,8 +41,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield list(topo.devices)
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 def _compile_program(program, sharding):
@@ -52,13 +57,18 @@ def _compile_program(program, sharding):
         .lower(x).compile()
 
 
-def _bmi_program(days: int) -> fp.FusedProgram:
+def _bmi_program(days: int, reduced: bool = False) -> fp.FusedProgram:
     ops = [fp.FusedOp("and", (0, 1))]
     for d in range(2, days):
         ops.append(fp.FusedOp("and", (days + len(ops) - 1, d)))
     ops.append(fp.FusedOp("popcount", (days + len(ops) - 1,)))
+    last = days + len(ops) - 1
     return fp.FusedProgram(width=32, n_inputs=days, ops=tuple(ops),
-                           outputs=(days + len(ops) - 1,))
+                           outputs=(last,),
+                           reduced=(last,) if reduced else ())
+
+
+_LANES = jax.ShapeDtypeStruct((), jnp.int32)  # a flush's real lane count
 
 
 def _largest_flushed_program(monkeypatch, keep_every_result: bool
@@ -124,3 +134,36 @@ def test_mul32_compiles(one_chip):
                               ops=(fp.FusedOp("mul", (0, 1)),),
                               outputs=(2,))
     _compile_program(program, one_chip)
+
+
+def test_summed_bmi_pipeline_compiles(one_chip):
+    """The Appendix B query as the engine now runs it on one chip: the
+    ``pallas-tpu`` pipeline with its popcount output summed on the
+    device returns one uint32 partial, not the lanes."""
+    program = _bmi_program(30, reduced=True)
+    pipeline = fp.build_vertical_pipeline(program, use_pallas=True)
+    leaf = jax.ShapeDtypeStruct((WORDS,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(pipeline).lower(*[leaf] * 30, lanes=_LANES).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes < 4096
+
+
+def test_summed_sharded_pipeline_keeps_partials_on_each_chip(four_chips):
+    """``shard-words`` over four described chips sums each chip's block
+    of the popcount on that chip: no collective, and the partials stay
+    split over the four."""
+    from repro.distributed.sharding import words_placement
+    program = _bmi_program(30, reduced=True)
+    placement = words_placement(four_chips)
+    leaf = jax.ShapeDtypeStruct((4 * WORDS,), jnp.int32,
+                                sharding=placement.sharding)
+    summed = fp.with_sums(program, fp.words_fn(program),
+                          devices=placement.devices,
+                          sharding=placement.sharding)
+    compiled = jax.jit(summed).lower(*[leaf] * 30, lanes=_LANES).compile()
+    text = compiled.as_text()
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute", "reduce-scatter"):
+        assert collective not in text
+    (out,) = compiled.output_shardings
+    assert out == placement.sharding

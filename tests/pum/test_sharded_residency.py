@@ -10,6 +10,8 @@ devices under their pipeline's placement.
   On four forced host devices the bitmap-index query's leaves and outputs
   are split over all four (a subprocess: the flag must be set before JAX
   starts, and the test process keeps its one CPU device).
+* The query's total is summed on each device: its AND bitmap is never an
+  output, and one uint32 partial a device crosses back.
 """
 
 import os
@@ -134,11 +136,18 @@ def test_four_devices_keep_the_sharded_leaves_resident():
             assert got == want, (got, want)
             c = dev.counters
             assert c["engine.flushes"] == 1
+            assert c["engine.sums.device"] == 1  # once a query
+            assert "engine.sums.host" not in c
             h = c.histogram("engine.flush_devices")
             assert h["min"] == h["max"] == 4  # outputs on all four
             placed.append(c["engine.leaf_bytes_placed"])
             place += [a for n, *_, a in tr.events if n == "flush.place"]
+            spans = {n: a for n, *_, a in tr.events}
+            assert spans["flush.materialize"]["n_outputs"] == 1  # no AND
+            # 128 uint32 partials a device cross back, not 2^13 lanes.
+            assert spans["flush.fetch"]["bytes"] == 4 * 128 * 4
             c.clear()
+
         assert placed == [days.nbytes, 0], placed
         assert [a["devices"] for a in place] == [4, 4]
         assert [a["bytes"] for a in place] == [days.nbytes, 0]
@@ -156,6 +165,13 @@ def test_four_devices_keep_the_sharded_leaves_resident():
             got = (acc & other).to_numpy()
         np.testing.assert_array_equal(got, days[0] & days[1] & other)
         assert dev.counters["engine.leaf_bytes_placed"] == other.nbytes
+
+        # Lanes past a multiple of the tile: the padding never counts.
+        odd = rng.integers(0, 1 << 64, (6, 1000), dtype=np.uint64)
+        odd[:, -1] = 2**64 - 1
+        got, _, _ = realworld.bmi_active_users(dev, odd, verify=False)
+        assert got == int(np.bitwise_count(
+            np.bitwise_and.reduce(odd, axis=0)).sum())
         dev.close()
         print("OK")
     """)

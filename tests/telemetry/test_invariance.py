@@ -120,6 +120,28 @@ def test_unpack_bytes_are_the_outputs_own(layout, monkeypatch):
         np.testing.assert_array_equal(got, want)
 
 
+def test_untraced_device_sum_reads_no_clock(monkeypatch):
+    """A bitmap-index query summed on the device gives the profiled
+    run's count with no tracer: no clock read, no annotation, and no
+    counter."""
+    from repro.core import realworld
+    days = np.random.default_rng(12).integers(0, 2**64, (4, 900),
+                                              dtype=np.uint64)
+    dev = pum.device(width=32, fuse=True)
+    with pum.profile(dev):
+        want, _, _ = realworld.bmi_active_users(dev, days, verify=False)
+    assert dev.counters["engine.sums.device"] == 1
+
+    def forbidden(*args, **kwargs):
+        raise AssertionError("the untraced flush path reached the tracer")
+
+    _forbid_tracing(monkeypatch, forbidden)
+    dev = pum.device(width=32, fuse=True)
+    got, _, _ = realworld.bmi_active_users(dev, days)  # NumPy-checked
+    assert got == want
+    assert len(dev.counters) == 0
+
+
 def test_counters_not_populated_without_tracer():
     """Zero-overhead contract: with no tracer attached the engine's
     CounterBank stays empty (no per-op work on the disabled path)."""
