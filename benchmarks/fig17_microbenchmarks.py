@@ -21,7 +21,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import Row, row, timed_us
-from repro.core.engine import PulsarEngine
+import repro.pum as pum
 
 KINDS = {
     "and": ("reduce_and", 64),
@@ -39,12 +39,10 @@ PAPER_AVG = {"M": 2.21, "H": 1.46}
 def run() -> list[Row]:
     rows: list[Row] = []
     for mfr in ("M", "H"):
-        pulsar = PulsarEngine(mfr=mfr, width=32, use_pulsar=True,
-                              controller="auto")
-        chained = PulsarEngine(mfr=mfr, width=32, use_pulsar=True,
-                               chained=True, controller="auto")
-        frac = PulsarEngine(mfr=mfr, width=32, use_pulsar=False,
-                            controller="auto")
+        cfg = pum.EngineConfig(mfr=mfr, width=32, controller="auto")
+        pulsar = pum.device(cfg).engine
+        chained = pum.device(cfg, chained=True).engine
+        frac = pum.device(cfg, use_pulsar=False).engine
         speeds = {}
 
         def bench():

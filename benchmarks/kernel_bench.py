@@ -145,9 +145,9 @@ def _bench_fused_mul() -> list[Row]:
 
 def _bench_fused_mul64() -> list[Row]:
     """Width-64 arithmetic through the fused pipeline: the 64-bit plane
-    layout routes to the additively registered ``words-cpu-64``
-    evaluator (NumPy word domain on CPU) — the program that used to be
-    forced onto the per-op eager path."""
+    layout runs on the ``words-cpu`` evaluator at ``word_bits=64`` (NumPy
+    word domain on CPU) — the program that used to be forced onto the
+    per-op eager path."""
     rng = np.random.default_rng(17)
     n = 32 * W
     width = 64
@@ -175,7 +175,7 @@ def _bench_fused_mul64() -> list[Row]:
             f"width {width})"),
         row("engine.fused_mul64", us_f,
             f"{16 * n / us_f:.0f} M ops*elem/s ({us_e / us_f:.1f}x over "
-            f"eager; 64-bit plane layout via words-cpu-64 — the jitted "
+            f"eager; 64-bit plane layout via words-cpu — the jitted "
             f"uint32-pair evaluator: carry-chained add/sub/mul and "
             f"Knuth-division divmod on lane pairs, one XLA trace; "
             f"bit_exact+stats_match={ok})"),
@@ -329,7 +329,7 @@ def _bench_autotuned() -> list[Row]:
         row("engine.autotuned_vs_static", us_s,
             f"static default {us_s:.0f}us vs tuned {us_t:.0f}us host "
             f"wall ({us_s / us_t:.2f}x; the plan minimizes the modeled "
-            f"PuM cost — on this CPU host the words-cpu-64 raw path is "
+            f"PuM cost — on this CPU host the 64-bit words-cpu raw path is "
             f"the same capability row as engine.fused_mul64; "
             f"bit_exact=True stats_match=True asserted — §Perf A0)"),
     ]

@@ -112,8 +112,9 @@ def run_bmi(phase, pum, realworld, jax, np, days, want, runs=BMI_RUNS,
     dev = pum.device(width=32, **DEVICE_KW, **device_kw)
     if not dev.config.fuse:
         fail(f"{phase}: the device fell back to eager execution")
-    name = dev.engine.fused_backend or pum.select_backend(
-        require="fused", width=32, layout=dev.layout).name
+    from repro.backends import fused_evaluator
+    name = fused_evaluator(dev.layout, leaf_bytes=days.nbytes,
+                           pinned=dev.config.fused_backend)
     log(phase, f"evaluator={name} days={DAYS} users={USERS} "
                f"bitmaps_mib={days.nbytes >> 20}")
     got = None
@@ -148,7 +149,7 @@ def one_chip(pum, realworld, jax, np, seed: int) -> None:
     # (a) the default evaluator: pallas-tpu on a TPU.
     _, c, name, _, _ = run_bmi("bmi", pum, realworld, jax, np, days, want)
     if name != "pallas-tpu":
-        fail(f"bmi: select_backend picked {name!r}, not 'pallas-tpu'")
+        fail(f"bmi: fused_evaluator chose {name!r}, not 'pallas-tpu'")
     if c.get("engine.leaf_cache.hits") <= 0:
         fail("bmi: warm runs never hit the leaf cache")
     if c.get("engine.pipeline_cache.hit") <= 0:
@@ -177,10 +178,10 @@ def one_chip(pum, realworld, jax, np, seed: int) -> None:
     dev = pum.device(width=SCAN_BITS, **DEVICE_KW)
     if not dev.config.fuse or dev.layout.word_bits != 32:
         fail("scan: expected a fused device on 32-bit lanes")
-    name = pum.select_backend(require="fused", width=SCAN_BITS,
-                              layout=dev.layout).name
+    from repro.backends import fused_evaluator
+    name = fused_evaluator(dev.layout, leaf_bytes=column.nbytes)
     if name != "pallas-tpu":
-        fail(f"scan: select_backend picked {name!r}, not 'pallas-tpu'")
+        fail(f"scan: fused_evaluator chose {name!r}, not 'pallas-tpu'")
     log("scan", f"evaluator={name} rows={SCAN_ROWS} bits={SCAN_BITS} "
                 f"range=[{c1}, {c2}]")
     with pum.profile(dev):
@@ -227,7 +228,7 @@ def four_chips(pum, realworld, jax, np, seed: int) -> None:
     got_p, c, name, _, _ = run_bmi("bmi-pallas", pum, realworld, jax, np,
                                    days, want, runs=1)
     if name != "pallas-tpu":
-        fail(f"bmi-pallas: select_backend picked {name!r}")
+        fail(f"bmi-pallas: fused_evaluator chose {name!r}")
     if got_p != got_sh:
         fail(f"shard-words counted {got_sh}, pallas-tpu counted {got_p}")
     log("bmi-pallas", f"count {got_p} equals the shard-words count")

@@ -32,22 +32,18 @@ from repro.launch.roofline import HBM_BW, PEAK_FLOPS
 HOST_BW = HBM_BW / 16.0            # bytes/s — host DRAM stream
 HOST_WORD_RATE = PEAK_FLOPS / 1e5  # word-ops/s — scalar/SIMD host lanes
 
-# Modeled relative throughput of each fused backend (word-rate and
-# bandwidth multipliers over the host anchors). ``ref-vertical`` is the
-# per-plane jnp oracle — priced so it can never win (it exists to
-# validate the others, mirroring its priority=-10 registration).
+# Modeled relative throughput of each fused evaluator of
+# ``repro.backends.EVALUATORS`` (word-rate and bandwidth multipliers over
+# the host anchors), the same at 32 and 64 bits: the 64-bit word path
+# runs the jitted uint32-pair evaluator on the same host anchors.
+# ``ref-vertical`` is the per-plane jnp oracle — priced so it can never
+# win (it exists to validate the others, and is only run by name).
 BACKEND_SPEED = {
     "words-cpu": (1.0, 1.0),
-    # words-cpu-64 runs the jitted uint32-pair evaluator (carry chained
-    # across lane pairs) — same host anchors as the 32-bit word path.
-    "words-cpu-64": (1.0, 1.0),
     "shard-words": (1.6, 1.6),
     "pallas-tpu": (8.0, 16.0),
-    "pallas-tpu-64": (8.0, 16.0),
     "ref-vertical": (0.05, 1.0),
-    "ref-vertical-64": (0.05, 1.0),
 }
-DEFAULT_SPEED = (0.5, 1.0)         # unknown registered backends
 
 # Fixed per-event costs (seconds): one staged dispatch, one jit trace,
 # one eager per-op dispatch (backend call + cost-plane charge — what a
@@ -163,7 +159,7 @@ class CostModel:
     def estimate(self, profile, knobs) -> Estimate:
         """Modeled seconds for one measured window re-run under
         ``knobs`` (see class docstring for the knob attributes)."""
-        word_rate, bw = self.speed.get(knobs.fused_backend, DEFAULT_SPEED)
+        word_rate, bw = self.speed[knobs.fused_backend]
         lanes = self._lanes(profile, knobs.word_bits)
         depth = max(1.0, profile.ops_per_flush)
         flushes = max(1, profile.flushes)

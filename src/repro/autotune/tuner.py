@@ -1,12 +1,13 @@
 """Tuner — deterministic search over the discrete execution-config space.
 
 ``Tuner.tune(profile, config)`` enumerates every candidate knob
-combination the host can actually run (fused backends resolved through
-the ``repro.backends`` registry, layouts covering the device width,
-auto-flush bounds, REF postponing where a controller exists, crossbar
-lookahead), scores each with the :class:`~repro.autotune.CostModel`, and
-freezes the winner into a :class:`TunedPlan`. The search is exhaustive
-and the enumeration order is sorted, so the same profile produces the
+combination the host can actually run (the fused evaluators of
+``repro.backends.host_evaluators``, on the layouts each runs that cover
+the device width; auto-flush bounds; REF postponing where a controller
+exists; crossbar lookahead), scores each with the
+:class:`~repro.autotune.CostModel`, and freezes the winner into a
+:class:`TunedPlan`. The search is exhaustive and the enumeration order
+is sorted, so the same profile produces the
 same plan in any process (pinned cross-process by
 ``tests/autotune/test_tuner.py``); the baseline (the config as-is) is
 scored first and candidates must *strictly* beat the incumbent — no
@@ -55,9 +56,9 @@ _KNOB_FIELDS = ("fused_backend", "word_bits", "flush_threshold",
 class SearchSpace:
     """The discrete config space the tuner enumerates.
 
-    ``backends=None`` resolves the candidate list from the backend
-    registry at tune time (every *available* backend with the
-    ``"fused"`` capability); thresholds of ``None`` mean "unbounded".
+    ``backends=None`` takes the candidate evaluators at tune time from
+    ``repro.backends.host_evaluators`` (those this host can run);
+    thresholds of ``None`` mean "unbounded".
     """
 
     backends: tuple | None = None
@@ -144,13 +145,6 @@ class TunedPlan:
                 changes["controller"] = "auto"
         return config.replace(**changes)
 
-    def selection_override(self):
-        """Context manager pinning this plan's fused backend in the
-        ``repro.backends`` registry (``selection_override``) — the hook
-        for callers that reach ``get_pipeline`` without a ``Device``."""
-        from repro.backends import selection_override
-        return selection_override("fused", self.fused_backend)
-
     # -- persistence ---------------------------------------------------- #
 
     def as_dict(self) -> dict:
@@ -207,12 +201,9 @@ class TunedPlan:
 
 def _config_knobs(config) -> dict:
     """The knob values ``config`` resolves to today (the baseline)."""
+    from repro.backends import fused_evaluator
     layout = config.resolved_layout()
-    name = config.fused_backend
-    if name is None:
-        from repro.backends import select_backend
-        name = select_backend(require="fused", width=config.width,
-                              layout=layout).name
+    name = fused_evaluator(layout, pinned=config.fused_backend)
     return dict(fused_backend=name, word_bits=layout.word_bits,
                 flush_threshold=config.flush_threshold,
                 flush_memory_bytes=config.flush_memory_bytes,
@@ -236,10 +227,8 @@ class Tuner:
     def _backend_names(self) -> list[str]:
         if self.space.backends is not None:
             return sorted(self.space.backends)
-        from repro.backends import available_backends, get_backend
-        return sorted(
-            n for n in available_backends("fused")
-            if get_backend(n).available())
+        from repro.backends import host_evaluators
+        return sorted(host_evaluators())
 
     def candidates(self, config) -> list[_Knobs]:
         """Every runnable candidate, in a deterministic order that lists
@@ -250,7 +239,7 @@ class Tuner:
         searched when the config already runs the ``"auto"`` controller
         — on the closed-form cost path a postponing change would
         silently mean nothing."""
-        from repro.backends import get_backend
+        from repro.backends import EVALUATORS
         base = _config_knobs(config)
 
         def order(values, key, sort=lambda v: (v is None, v or 0)):
@@ -275,10 +264,7 @@ class Tuner:
                 if wb < config.width:
                     continue
                 for name in backends:
-                    spec = get_backend(name)
-                    if "fused" not in spec.capabilities \
-                            or spec.max_width < config.width \
-                            or wb not in spec.layouts:
+                    if wb not in EVALUATORS[name][1]:
                         continue
                     for t in thresholds:
                         for m in mem:

@@ -1,12 +1,10 @@
 """PulsarEngine — the PuM compute engine behind the ``repro.pum`` API.
 
-The public way to use this system is :mod:`repro.pum` (``PumArray``
-operator frontend + ``Device``/``EngineConfig`` + the backend registry);
-``PulsarEngine``'s dataplane *method* surface (``add``/``and_``/…) is kept
-as a thin compat shim that emits ``DeprecationWarning`` and delegates to
-the private implementations the new API calls directly. Construction,
-``stats``/``reset_stats``, ``flush`` and the cost-plane helpers
-(``op_effective_ns``) are NOT deprecated — ``Device`` wraps them.
+The public way to use this system is :mod:`repro.pum`: ``PumArray``
+operators call the engine's private op implementations (``_add``,
+``_and``, ...), and a ``Device`` builds the engine from one
+``EngineConfig`` (``PulsarEngine(config)``) and wraps its ``stats``,
+``flush`` and cost-plane helpers (``op_effective_ns``).
 
 Two coupled planes:
   * dataplane: bit-exact results. ``backend="fast"`` computes on packed
@@ -58,10 +56,10 @@ Plane layouts: the lane word format is an explicit
 :class:`~repro.kernels.plane_layout.PlaneLayout` (default: the narrowest
 canonical layout holding ``width`` — 32-bit up to width 32, 64-bit
 above). The fused pipeline, leaf snapshots and the raw lane split all
-derive from it, and evaluator selection filters the backend registry by
-it — width-64 fused execution is the 64-bit layout plus the additively
-registered ``*-64`` evaluators, not a special case. ``fused_backend``
-pins a specific registered fused evaluator by name (e.g. the
+derive from it, and so does the evaluator a flush runs on
+(``repro.backends.fused_evaluator``): width-64 fused execution is the
+64-bit layout on the same evaluators, not a special case.
+``EngineConfig.fused_backend`` pins one evaluator by name (e.g. the
 multi-device ``"shard-words"`` pipeline).
 """
 
@@ -72,15 +70,15 @@ import contextlib
 import dataclasses
 import itertools
 import threading
-import warnings
 import weakref
 from concurrent.futures import ThreadPoolExecutor
+from typing import TYPE_CHECKING
 
 import jax
 import numpy as np
 
-from repro.backends import get_backend, select_backend
-from repro.core.charact import SuccessRateDb, default_db
+from repro.backends import build_sim_dataplane
+from repro.core.charact import default_db
 from repro.core.cost_model import CostModel, OpCost, ZERO
 from repro.core.geometry import PAPER_MODULE
 from repro.core.profiles import PROFILES
@@ -88,18 +86,11 @@ from repro.kernels import fused_program as _fused
 from repro.kernels.fused_program import (FusedOp, FusedProgram, get_pipeline,
                                          optimize_program,
                                          with_fault_injection)
-from repro.kernels.plane_layout import (PlaneLayout, get_layout,
-                                        layout_for_width)
+from repro.kernels.plane_layout import PlaneLayout
 from repro.telemetry import NULL_TRACER, CounterBank
 
-
-def _warn_deprecated(method: str, replacement: str) -> None:
-    """One-line compat-shim warning: the PulsarEngine op methods survive
-    for out-of-tree callers, but in-repo code goes through repro.pum."""
-    warnings.warn(
-        f"PulsarEngine.{method}() is deprecated; use {replacement} "
-        f"(repro.pum — migration table in docs/api.md)",
-        DeprecationWarning, stacklevel=3)
+if TYPE_CHECKING:
+    from repro.pum.config import EngineConfig
 
 
 @dataclasses.dataclass
@@ -733,73 +724,33 @@ class PulsarEngine:
     to disable either bound. ``donate_leaves=True`` donates the fused
     pipeline's leaf device buffers to the compiled trace (cuts peak
     memory; bit-exactness unaffected — the engine's snapshots live on the
-    host). The ``backend`` name resolves through the ``repro.backends``
-    registry (capability ``"eager"``): ``"fast"`` computes on packed
-    NumPy words, ``"sim"`` routes through the bit-exact chip model.
+    host). ``backend="fast"`` computes on packed NumPy words, ``"sim"``
+    routes through the bit-exact chip model. Every knob is a field of the
+    one :class:`~repro.pum.config.EngineConfig` the engine is built from
+    (``self.config``), validated there.
     """
 
-    def __init__(self, mfr: str = "M", width: int = 32,
-                 row_bits: int = 65536, banks: int = 16,
-                 backend: str = "fast",
-                 success_db: SuccessRateDb | None = None,
-                 use_pulsar: bool = True, chained: bool = False,
-                 controller=None, seed: int = 0, fuse: bool = False,
-                 flush_threshold: int | None = 1024,
-                 flush_memory_bytes: int | None = 1 << 30,
-                 donate_leaves: bool = False, layout=None,
-                 fused_backend: str | None = None,
-                 ref_postponing: int = 1, reliability=None,
-                 cmd_buffer_lookahead: int = 8,
-                 leaf_cache_bytes: int | None = 1 << 26):
-        self.profile = PROFILES[mfr]
-        self.mfr = mfr
-        self.width = width
-        self.row_bits = row_bits
-        self.banks = banks
-        self.backend = backend
-        self.seed = seed
-        self.use_pulsar = use_pulsar  # False => FracDRAM baseline costs
-        self.chained = chained and use_pulsar  # chained-staging (§Perf P4)
+    def __init__(self, config: EngineConfig):
+        self.config = config
+        self.profile = PROFILES[config.mfr]
+        # FracDRAM baseline costs (use_pulsar=False) have no chained
+        # staging (§Perf P4).
+        self.chained = config.chained and config.use_pulsar
         # Plane layout: the lane word format of the fused dataplane.
-        # Default: the narrowest canonical layout holding `width` bits
-        # (width <= 32 keeps the exact pre-layout 32-bit behavior).
-        self.layout = (layout_for_width(width) if layout is None
-                       else get_layout(layout))
-        if width > self.layout.word_bits:
-            raise ValueError(
-                f"width {width} does not fit the {self.layout.word_bits}"
-                f"-bit plane layout {self.layout.name!r}")
+        self.layout = config.resolved_layout()
         # controller="auto" builds a MemoryController over `banks` banks;
         # None keeps the legacy closed-form bank divide (reproduces the
-        # pre-controller numbers exactly). `ref_postponing` batches up to
-        # N REF commands into one rank lockout (JEDEC allows 8) — longer
-        # but rarer refresh windows, priced by batch_cost.
-        if not 1 <= ref_postponing <= 8:
-            raise ValueError(
-                f"ref_postponing must be in [1, 8] (JEDEC allows "
-                f"postponing up to 8 REFs), got {ref_postponing}")
-        if ref_postponing != 1 and controller != "auto":
-            # Loud, not silently inert: the closed-form path never models
-            # refresh, and a prebuilt controller carries its own policy.
-            raise ValueError(
-                "ref_postponing requires controller='auto' (with "
-                "controller=None refresh is not modeled; a prebuilt "
-                "MemoryController sets postponing= itself)")
-        if cmd_buffer_lookahead < 1:
-            raise ValueError(f"cmd_buffer_lookahead must be >= 1, got "
-                             f"{cmd_buffer_lookahead}")
+        # pre-controller numbers exactly).
+        controller = config.controller
         if controller == "auto":
             from repro.controller import MemoryController
-            controller = MemoryController(n_banks=banks,
-                                          postponing=ref_postponing,
-                                          lookahead=cmd_buffer_lookahead)
+            controller = MemoryController(
+                n_banks=config.banks, postponing=config.ref_postponing,
+                lookahead=config.cmd_buffer_lookahead)
         self.controller = controller
-        self.ref_postponing = ref_postponing
-        # Crossbar command-buffer depth for concurrent-stream scheduling;
-        # execution-only (never priced by the single-stream cost plane).
-        self.cmd_buffer_lookahead = cmd_buffer_lookahead
-        self.cost = CostModel(row_bits=row_bits, controller=controller)
-        self.db = success_db or default_db()
+        self.cost = CostModel(row_bits=config.row_bits,
+                              controller=controller)
+        self.db = config.success_db or default_db()
         # Concurrency state: one recording slot + one EngineStats shard
         # per client context (a thread, or a named ``client()`` scope).
         # The RLock guards all record-side mutation (slots, shards, cost
@@ -816,73 +767,15 @@ class PulsarEngine:
         self._async_slots = threading.BoundedSemaphore(2)
         self._best_cfg_cache: dict[int, tuple[int, int, float]] = {}
         self._batch_cache: dict[tuple, object] = {}
-        # Eager-dataplane backend by registry lookup: the builder returns
-        # None for the packed-NumPy word dataplane or an ALU-protocol
-        # object (see repro.backends.BackendSpec) to route ops through.
-        spec = get_backend(backend)
-        if "eager" not in spec.capabilities:
-            raise ValueError(
-                f"backend {backend!r} has no eager dataplane "
-                f"(capabilities: {sorted(spec.capabilities)})")
-        if width > spec.max_width:
-            raise ValueError(
-                f"backend {backend!r} supports width <= {spec.max_width}, "
-                f"got {width}")
-        if not spec.available():
-            raise ValueError(f"backend {backend!r} is registered but not "
-                             f"available on this host")
-        self._alu = spec.builder(self)
-        if fuse and self._alu is not None:
-            raise ValueError(
-                f"fuse=True requires an eager word-dataplane backend "
-                f"(builder returns None, e.g. 'fast'); backend "
-                f"{backend!r} routes ops through an ALU and stays "
-                f"per-op")
-        if fused_backend is not None:
-            fspec = get_backend(fused_backend)
-            if "fused" not in fspec.capabilities:
-                raise ValueError(
-                    f"fused_backend {fused_backend!r} has no fused "
-                    f"evaluator (capabilities: "
-                    f"{sorted(fspec.capabilities)})")
-            if width > fspec.max_width \
-                    or self.layout.word_bits not in fspec.layouts:
-                raise ValueError(
-                    f"fused_backend {fused_backend!r} covers width <= "
-                    f"{fspec.max_width} on layouts "
-                    f"{sorted(fspec.layouts)}; engine is width {width} "
-                    f"on the {self.layout.word_bits}-bit layout")
-        elif fuse:
-            # Layout capability query (replaces the old hardwired
-            # `width > 32` guard): some registered fused evaluator must
-            # cover this width on this plane layout. pum.Device falls
-            # back to eager automatically when nothing does.
-            try:
-                select_backend(require="fused", width=width,
-                               layout=self.layout)
-            except LookupError as e:
-                raise ValueError(
-                    f"no registered fused evaluator covers width {width} "
-                    f"on the {self.layout.word_bits}-bit plane layout "
-                    f"({e}); use fuse=False or register_backend() one"
-                ) from None
-        if flush_threshold is not None and flush_threshold < 1:
-            raise ValueError("flush_threshold must be >= 1 or None")
-        if leaf_cache_bytes is not None and leaf_cache_bytes < 0:
-            raise ValueError(
-                f"leaf_cache_bytes must be >= 0 or None (0/None disables "
-                f"the leaf cache), got {leaf_cache_bytes}")
-        self.fuse = fuse
-        self.fused_backend = fused_backend
-        self.flush_threshold = flush_threshold
-        self.flush_memory_bytes = flush_memory_bytes
-        self.donate_leaves = donate_leaves
+        # Eager dataplane: None computes on packed NumPy words ("fast");
+        # "sim" routes small operands through the chip model's ALU.
+        self._alu = (build_sim_dataplane(config)
+                     if config.backend == "sim" else None)
         # Device-resident leaf cache: staged leaf uploads keyed on the
         # caller's buffer + content fingerprint, shared across all client
         # contexts of this engine (one cache per device). 0/None disables.
-        self.leaf_cache_bytes = leaf_cache_bytes or 0
-        self._leaf_cache = (_LeafCache(leaf_cache_bytes)
-                            if leaf_cache_bytes else None)
+        self._leaf_cache = (_LeafCache(config.leaf_cache_bytes)
+                            if config.leaf_cache_bytes else None)
         # Telemetry: counters always exist (cheap dict, written only while
         # a tracer is attached); ``tracer`` is None until someone opts in
         # (pum.profile(), ServeEngine(telemetry=True)) — the disabled path
@@ -900,15 +793,10 @@ class PulsarEngine:
         # (default) keeps every path exactly as before — the enabled check
         # is a single `is None` per flush, like the tracer.
         self.reliability = None
-        if reliability is not None:
+        if config.reliability is not None:
             from repro.reliability import ReliabilityPlane
             self.reliability = ReliabilityPlane(
-                reliability, mfr=mfr, counters=self.counters)
-            if self.reliability.inject and not fuse:
-                raise ValueError(
-                    "reliability fault injection hooks the fused dispatch "
-                    "path; it requires fuse=True (eager ops never run the "
-                    "vote/retry loop)")
+                config.reliability, mfr=config.mfr, counters=self.counters)
 
     # ------------------------------------------------------------------ #
     # Client contexts (per-thread / named recording slots + stats shards)
@@ -983,7 +871,7 @@ class PulsarEngine:
     def _kind_cost(self, kind: str, m: int, n_rg: int, w: int,
                    n_planes: int | None, n_rg3: int | None = None) -> OpCost:
         fs = self.profile.frac_supported
-        ps = "pow2" if self.use_pulsar else "max"
+        ps = "pow2" if self.config.use_pulsar else "max"
         kw = dict(frac_supported=fs, plan_style=ps)
         ckw = dict(kw, chained=self.chained)
         c = self.cost
@@ -1019,9 +907,9 @@ class PulsarEngine:
         latency / success_rate — the paper's per-op configuration search
         ("we choose the N_RG that produces the highest throughput").
         Arithmetic kinds search MAJ3/MAJ5 sub-op configs independently."""
-        if not self.use_pulsar:
+        if not self.config.use_pulsar:
             # FracDRAM baseline: MAJ3 on 4-row activation only.
-            sr = self.db.mean(self.mfr, 3, 4)
+            sr = self.db.mean(self.config.mfr, 3, 4)
             return 3, 4, sr, self._kind_cost(kind, 3, 4, w, n_planes, 4)
         key = (kind, w, n_planes)
         if key not in self._best_cfg_cache:
@@ -1040,7 +928,7 @@ class PulsarEngine:
                     s = rel.plan_success(m, n)
                     if s is not None:
                         return s
-                return self.db.mean(self.mfr, m, n, plan_style="pow2")
+                return self.db.mean(self.config.mfr, m, n, plan_style="pow2")
 
             candidates: list[tuple[int, int, int | None]] = []
             if kind in self._ARITH:
@@ -1083,7 +971,7 @@ class PulsarEngine:
         return self._best_cfg_cache[key]
 
     def _n_vec_rows(self, n_elems: int) -> int:
-        return -(-n_elems // self.row_bits)
+        return -(-n_elems // self.config.row_bits)
 
     def _batch_for(self, kind: str, m: int, n_rg: int):
         """Controller-measured bank-batch cost for this op's dominant
@@ -1103,14 +991,14 @@ class PulsarEngine:
             else:
                 unit = self.cost.maj_unit_programs(
                     m, n_rg, frac_supported=self.profile.frac_supported,
-                    plan_style="pow2" if self.use_pulsar else "max",
+                    plan_style="pow2" if self.config.use_pulsar else "max",
                     # Chained staging keeps one input resident per MAJ, so
                     # measure bank contention on the thinner command stream.
                     resident_inputs=1 if self.chained else 0)
-            order = (tuple(self.reliability.bank_order(self.banks))
+            order = (tuple(self.reliability.bank_order(self.config.banks))
                      if self.reliability is not None else None)
             self._batch_cache[key] = self.controller.batch_cost(
-                unit, self.banks, bank_order=order)
+                unit, self.config.banks, bank_order=order)
         return self._batch_cache[key]
 
     def _charge(self, kind: str, n_elems: int, width: int | None = None,
@@ -1121,7 +1009,7 @@ class PulsarEngine:
                 # Program capture records the charge recipe so replays
                 # price identically to the uncaptured path.
                 log.append((kind, n_elems, width, n_planes))
-            w = width or self.width
+            w = width or self.config.width
             m, n, sr, cost = self._cfg_for(kind, w, n_planes)
             if self.reliability is not None:
                 # The flush-time vote loop injects at the worst config used.
@@ -1129,7 +1017,7 @@ class PulsarEngine:
             batch = (self._batch_for(kind, m, n)
                      if self.controller is not None else None)
             self._stats_shard().charge(cost, self._n_vec_rows(n_elems),
-                                       self.banks, sr, batch)
+                                       self.config.banks, sr, batch)
 
     def _replay_charges(self, recipe) -> None:
         """Re-apply a captured charge recipe (one replayed program)."""
@@ -1144,10 +1032,10 @@ class PulsarEngine:
         controller the latency is priced through the scheduled bank batch
         (tFAW/tRRD-limited speedup + refresh factor); without one it is the
         closed-form single-bank latency divided by ``banks``."""
-        w = width or self.width
+        w = width or self.config.width
         m, n, sr, cost = self._cfg_for(kind, w, n_planes)
         if self.controller is None:
-            return cost.latency_ns / self.banks, sr, m, n
+            return cost.latency_ns / self.config.banks, sr, m, n
         b = self._batch_for(kind, m, n)
         eff = (cost.latency_ns / max(1.0, b.parallel_speedup)
                * b.refresh_factor)
@@ -1172,7 +1060,7 @@ class PulsarEngine:
         return x.materialize() if isinstance(x, LazyArray) else x
 
     def _can_fuse(self, *operands) -> bool:
-        if not self.fuse:
+        if not self.config.fuse:
             return False
         shape = operands[0].shape
         return all(x.shape == shape for x in operands[1:])
@@ -1185,8 +1073,8 @@ class PulsarEngine:
             if x._value is None:
                 return x._graph is not None and x._graph.raw
             x = x._value
-        return bool(self.width < 64 and x.size
-                    and int(x.max()) >> self.width)
+        return bool(self.config.width < 64 and x.size
+                    and int(x.max()) >> self.config.width)
 
     def _use_raw(self, operands: tuple) -> bool:
         """Plane-wise ops route through the raw packed-bitmap graph when
@@ -1243,7 +1131,7 @@ class PulsarEngine:
             g = self._graph
             if g is None:
                 g = self._graph = _OpGraph(
-                    n, self.layout.word_bits if raw else self.width,
+                    n, self.layout.word_bits if raw else self.config.width,
                     self.layout, raw=raw, cache=self._leaf_cache)
                 if self.tracer is not None:
                     g.flush_id = next(self._flush_ids)
@@ -1315,13 +1203,13 @@ class PulsarEngine:
         Returns the trigger name ("ops"/"memory"/"vmem", doubling as the
         telemetry counter suffix) or None when the graph may keep
         growing."""
-        if self.flush_threshold is not None \
-                and len(g.ops) >= self.flush_threshold:
+        if self.config.flush_threshold is not None \
+                and len(g.ops) >= self.config.flush_threshold:
             return "ops"
-        if self.flush_memory_bytes is not None:
+        if self.config.flush_memory_bytes is not None:
             est = g.layout.nbytes_per_word * g.n \
                 * (len(g.leaves) + len(g.ops))
-            if est >= self.flush_memory_bytes:
+            if est >= self.config.flush_memory_bytes:
                 return "memory"
         if _fused.block_bytes(len(g.leaves) + len(g.ops), g.width) \
                 >= _fused.VMEM_BLOCK_BUDGET:
@@ -1630,8 +1518,8 @@ class PulsarEngine:
             if self.tracer is not None:
                 misses0 = _fused._cached_pipeline.cache_info().misses
             pipeline = get_pipeline(
-                program, donate=self.donate_leaves,
-                backend=self.fused_backend,
+                program, donate=self.config.donate_leaves,
+                backend=self.config.fused_backend,
                 leaf_bytes=sum(x.nbytes for x in leaves))
             if self.tracer is not None:
                 hit = (_fused._cached_pipeline.cache_info().misses
@@ -1741,7 +1629,7 @@ class PulsarEngine:
         wants = getattr(pipeline, "wants_device", None)
         wire_words = (g.n + g._pad) * g.layout.wire_words_per_lane
         on_dev = wants is not None and wants(wire_words)
-        use_dev = on_dev and not self.donate_leaves
+        use_dev = on_dev and not self.config.donate_leaves
         placement = getattr(pipeline, "placement", None)
         placed = 0
         out = []
@@ -1752,7 +1640,7 @@ class PulsarEngine:
                     placed += moved
                     out.append(buf)
                     continue
-                if self.donate_leaves:
+                if self.config.donate_leaves:
                     cache.drop_device(x)
                 x = x.wire
             if on_dev:
@@ -1787,16 +1675,19 @@ class PulsarEngine:
         return self._binary("xor2", "xor", a, b, lambda x, y: x ^ y)
 
     def _add(self, a, b):
-        return self._binary("add", "add", a, b,
-                            lambda x, y: (x + y) & self._mask(self.width))
+        return self._binary(
+            "add", "add", a, b,
+            lambda x, y: (x + y) & self._mask(self.config.width))
 
     def _sub(self, a, b):
-        return self._binary("add", "sub", a, b,
-                            lambda x, y: (x - y) & self._mask(self.width))
+        return self._binary(
+            "add", "sub", a, b,
+            lambda x, y: (x - y) & self._mask(self.config.width))
 
     def _mul(self, a, b):
-        return self._binary("mul", "mul", a, b,
-                            lambda x, y: (x * y) & self._mask(self.width))
+        return self._binary(
+            "mul", "mul", a, b,
+            lambda x, y: (x * y) & self._mask(self.config.width))
 
     def _divpart(self, a, b, which: str):
         """div or mod: ONE restoring-division charge; in fused mode the op
@@ -1860,7 +1751,7 @@ class PulsarEngine:
 
     def _popcount(self, a, width: int | None = None):
         a = self._coerce(a)
-        w = width or self.width
+        w = width or self.config.width
         self._charge("popcount", a.size, n_planes=w)
         if self._can_fuse(a):
             # Raw packed-bitmap graphs keep popcount planewise on the
@@ -1874,7 +1765,7 @@ class PulsarEngine:
 
     def _reduce_bits(self, a, kind: str, width: int | None = None):
         a = self._coerce(a)
-        w = width or self.width
+        w = width or self.config.width
         self._charge(f"reduce_{kind}", a.size, n_planes=w)
         if self._can_fuse(a):
             return self._record(f"reduce_{kind}", (a,),
@@ -1886,76 +1777,6 @@ class PulsarEngine:
             return (a != 0).astype(np.uint64)
         pc = _vec_popcount(a)
         return pc & np.uint64(1)
-
-    # -- deprecated compat shim (the pre-repro.pum method surface) ------ #
-    # Each method is a one-line delegate that warns once per call site;
-    # semantics are identical to the private implementations above.
-
-    def and_(self, a, b):
-        """Deprecated: use ``&`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("and_", "PumArray.__and__ (a & b)")
-        return self._and(a, b)
-
-    def or_(self, a, b):
-        """Deprecated: use ``|`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("or_", "PumArray.__or__ (a | b)")
-        return self._or(a, b)
-
-    def xor(self, a, b):
-        """Deprecated: use ``^`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("xor", "PumArray.__xor__ (a ^ b)")
-        return self._xor(a, b)
-
-    def add(self, a, b):
-        """Deprecated: use ``+`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("add", "PumArray.__add__ (a + b)")
-        return self._add(a, b)
-
-    def sub(self, a, b):
-        """Deprecated: use ``-`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("sub", "PumArray.__sub__ (a - b)")
-        return self._sub(a, b)
-
-    def mul(self, a, b):
-        """Deprecated: use ``*`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("mul", "PumArray.__mul__ (a * b)")
-        return self._mul(a, b)
-
-    def div(self, a, b):
-        """Deprecated: use ``//`` on :class:`repro.pum.PumArray`.
-        Unsigned floor division; lanes dividing by zero yield 0 (the
-        NumPy unsigned semantics, preserved bit-exactly when fused)."""
-        _warn_deprecated("div", "PumArray.__floordiv__ (a // b)")
-        return self._div(a, b)
-
-    def mod(self, a, b):
-        """Deprecated: use ``%`` on :class:`repro.pum.PumArray`.
-        Unsigned remainder, priced as one division (the restoring divider
-        computes the remainder alongside the quotient); lanes with a zero
-        divisor yield 0."""
-        _warn_deprecated("mod", "PumArray.__mod__ (a % b)")
-        return self._mod(a, b)
-
-    def divmod(self, a, b):
-        """Deprecated: use ``divmod()`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("divmod", "PumArray.__divmod__ (divmod(a, b))")
-        return self._divmod(a, b)
-
-    def less_than(self, a, b):
-        """Deprecated: use ``<`` on :class:`repro.pum.PumArray`."""
-        _warn_deprecated("less_than", "PumArray.__lt__ (a < b)")
-        return self._less_than(a, b)
-
-    def popcount(self, a, width: int | None = None):
-        """Deprecated: use :meth:`repro.pum.PumArray.popcount`."""
-        _warn_deprecated("popcount", "PumArray.popcount()")
-        return self._popcount(a, width)
-
-    def reduce_bits(self, a, kind: str, width: int | None = None):
-        """Deprecated: use :meth:`repro.pum.PumArray.reduce_bits`.
-        Per-element AND/OR/XOR reduction across the element's bits."""
-        _warn_deprecated("reduce_bits", "PumArray.reduce_bits(kind)")
-        return self._reduce_bits(a, kind, width)
 
     def _alu_load2(self, a: np.ndarray, b: np.ndarray):
         """Both operands into sim-ALU vertical registers (one row budget:

@@ -6,8 +6,7 @@ device's cost plane, the CPU number is the measured NumPy wall time on this
 host (a *context* number — the paper measured a Skylake with AVX-512).
 
 Kernels consume the public :mod:`repro.pum` API: each takes a
-:class:`~repro.pum.Device` (a legacy ``PulsarEngine`` is coerced via
-``pum.as_device``) and computes through ``PumArray`` operators. Every
+:class:`~repro.pum.Device` and computes through ``PumArray`` operators. Every
 kernel runs unchanged on an eager (``fuse=False``) or fused
 (``fuse=True``) device and produces identical results and EngineStats:
 the packed-bitmap set intersections (BMI/TC/KCS) route through the raw
@@ -35,7 +34,7 @@ import time
 import numpy as np
 
 from repro.core.engine import _vec_popcount
-from repro.pum import Device, as_device
+from repro.pum import Device
 
 
 def _timed(fn):
@@ -70,7 +69,6 @@ def bmi_active_users(dev: Device, daily_bitmaps: np.ndarray,
     were active every day (Fig 20's BMI query). With ``verify=False`` the
     NumPy oracle and the assertion are skipped and cpu_ms reads 0.0 —
     benchmark harnesses verify once, then time the device path alone."""
-    dev = as_device(dev)
     days = daily_bitmaps.shape[0]
 
     def cpu():
@@ -90,7 +88,6 @@ def bmi_active_users(dev: Device, daily_bitmaps: np.ndarray,
 def bitweaving_scan(dev: Device, column: np.ndarray, c1: int,
                     c2: int) -> tuple[int, float, float]:
     """select count(*) from T where c1 <= col <= c2 (BitWeaving [62])."""
-    dev = as_device(dev)
 
     def cpu():
         return int(((column >= c1) & (column <= c2)).sum())
@@ -120,7 +117,6 @@ def triangle_count(dev: Device, adj_bits: np.ndarray
     """adj_bits: [n, n] {0,1} adjacency (undirected, no self-loops).
     Triangles = sum_{u<v, (u,v) in E} |N(u) & N(v)| / 3 via bitwise AND of
     packed adjacency rows (set-centric SISA style [10])."""
-    dev = as_device(dev)
     n = adj_bits.shape[0]
     packed = np.packbits(adj_bits, axis=1, bitorder="little")
     packed64 = np.zeros((n, (packed.shape[1] + 7) // 8 * 8), np.uint8)
@@ -201,7 +197,6 @@ def kclique_star(dev: Device, adj_bits: np.ndarray,
     repeat calls are pointer-stable and hit the leaf cache. Ragged clique
     lists fall back to the per-clique loop. With ``verify=False`` the NumPy
     oracle and assertion are skipped and cpu_ms reads 0.0."""
-    dev = as_device(dev)
     rows, stacks = _kcs_operands(adj_bits, cliques)
 
     def cpu():
@@ -229,7 +224,6 @@ def knn_distances(dev: Device, queries: np.ndarray,
     """Quantized (8-bit) squared-L2 distances, kNN front half: for each query
     compute distances to all refs; argmin on host (as in the paper, the
     host reads back and selects)."""
-    dev = as_device(dev)
     q = queries.astype(np.int64)
     r = refs.astype(np.int64)
 
@@ -257,7 +251,6 @@ def image_segmentation(dev: Device, img: np.ndarray,
                        colors: np.ndarray) -> tuple[np.ndarray, float, float]:
     """Assign each pixel the nearest of C colors (1-D intensity model,
     per-pixel |p - c| compare network), PuM-side compares + mux."""
-    dev = as_device(dev)
     p = img.ravel().astype(np.int64)
 
     def cpu():
@@ -287,7 +280,6 @@ def xnor_conv_cost(dev: Device, in_ch: int, out_ch: int,
                    kh: int, kw: int, oh: int, ow: int) -> float:
     """Op-count latency model of one binarized conv layer (XNOR-Net [92]):
     per output: XNOR over in_ch*kh*kw bits + popcount + sign. Returns ms."""
-    dev = as_device(dev)
     dev.reset_stats()
     n_out = out_ch * oh * ow
     bits = in_ch * kh * kw

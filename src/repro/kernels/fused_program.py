@@ -74,13 +74,12 @@ literals, and the vertical pack/unpack tiles a 64-bit lane as two 32x32
 transposes. The pipeline ABI stays flat int32 "wire" arrays
 (``layout.wire_words_per_lane`` words per lane) at every layout.
 
-Backend selection goes through the registry in :mod:`repro.backends`
-(capability ``"fused"``): on TPU the ``pallas-tpu`` evaluator wins by
-priority, elsewhere ``words-cpu``; ``ref-vertical`` is requestable by
-name for validation. Backends declare the layouts they consume — the
-64-bit evaluators (``words-cpu-64``/``pallas-tpu-64``/``ref-vertical-64``)
-and the multi-device ``shard-words`` pipeline are additive
-``register_backend`` calls over the same builders.
+The evaluator is chosen by :func:`repro.backends.fused_evaluator`: on a
+TPU ``pallas-tpu``, elsewhere ``words-cpu``, and ``shard-words`` where a
+32-bit flush spreads over several devices; ``ref-vertical`` runs only
+by name, for validation. Each runs the layouts its
+:data:`repro.backends.EVALUATORS` entry lists (``shard-words``: 32-bit
+only).
 
 Before compilation the engine normalizes each recorded graph with
 ``optimize_program`` (common-subexpression elimination + dead-node/leaf
@@ -102,8 +101,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.backends import (device_memory, get_backend, select_backend,
-                            shards_over_devices)
+from repro.backends import EVALUATORS, fused_evaluator
 from repro.kernels import ref
 from repro.kernels.bit_transpose import bit_transpose32 as _pl_transpose
 from repro.kernels.plane_layout import LAYOUT32, PlaneLayout
@@ -824,12 +822,11 @@ def with_sums(program: FusedProgram, core, xp=jnp, devices: int = 1,
 
 # --------------------------------------------------------------------- #
 # End-to-end pipeline: pack -> run -> unpack, one jit trace, cached.
-# Evaluator chosen by capability lookup in the repro.backends registry.
+# Evaluator chosen by repro.backends.fused_evaluator.
 # --------------------------------------------------------------------- #
 
 
-def get_pipeline(program: FusedProgram, force_pallas: bool = False,
-                 interpret: bool = False, force_vertical: bool = False,
+def get_pipeline(program: FusedProgram, interpret: bool = False,
                  donate: bool = False, backend: str | None = None,
                  leaf_bytes: int = 0):
     """Compiled callable for ``program``: ``fn(*leaves) -> tuple(outs)``.
@@ -837,54 +834,31 @@ def get_pipeline(program: FusedProgram, force_pallas: bool = False,
     Leaves are flat int32 *wire* arrays of packed horizontal words
     (``program.layout.wire_words_per_lane`` int32 words per lane, lane
     count a multiple of 32); outputs likewise. One jit trace end to
-    end. The evaluator is resolved through the backend registry
-    (``repro.backends``, capability ``"fused"``, filtered by the
-    program's layout): on TPU the Pallas vertical evaluator wins
-    (operands bit-transpose once, the fused program runs per VMEM block,
-    outputs transpose back once); elsewhere the word-domain evaluator
-    runs. ``backend=`` names a registered evaluator explicitly;
-    ``force_pallas``/``force_vertical`` are shorthands for the built-in
-    names at the program's layout. ``leaf_bytes`` (the flush's operand
-    bytes) puts an unnamed choice to the size rule
-    (:func:`repro.backends.shards_over_devices`): leaves that outgrow one
-    chip of a multi-device host go to ``shard-words``. With
-    ``donate=True`` the leaf device buffers are donated to the trace
-    (``donate_argnums``) so XLA may reuse them for intermediates — the
-    engine's leaf snapshots stay on the host, so donation never
-    invalidates caller-visible data. Cached on (program structure,
-    backend, donate); jit handles per-shape specialization. A program
-    with ``reduced`` outputs gives ``fn(*leaves, lanes=n)``, ``n`` the
-    real lane count, and returns those outputs as uint32 partial sums
-    (:func:`with_sums`).
+    end. The evaluator is :func:`repro.backends.fused_evaluator`'s
+    choice for the program's layout: ``backend=`` pins one by name, and
+    ``leaf_bytes`` (the flush's operand bytes) puts an unpinned choice
+    to the size rule, so leaves that outgrow one chip of a multi-device
+    host go to ``shard-words``. On a TPU the Pallas vertical evaluator
+    runs (operands bit-transpose once, the fused program runs per VMEM
+    block, outputs transpose back once); elsewhere the word-domain
+    evaluator. With ``donate=True`` the leaf device buffers are donated
+    to the trace (``donate_argnums``) so XLA may reuse them for
+    intermediates — the engine's leaf snapshots stay on the host, so
+    donation never invalidates caller-visible data. Cached on (program
+    structure, evaluator, donate); jit handles per-shape specialization.
+    A program with ``reduced`` outputs gives ``fn(*leaves, lanes=n)``,
+    ``n`` the real lane count, and returns those outputs as uint32
+    partial sums (:func:`with_sums`).
     """
-    wb = program.layout.word_bits
-    if backend is None:
-        if force_pallas:
-            backend = "pallas-tpu" if wb == 32 else f"pallas-tpu-{wb}"
-        elif force_vertical:
-            backend = "ref-vertical" if wb == 32 else f"ref-vertical-{wb}"
-        else:
-            backend = select_backend(require="fused", width=program.width,
-                                     layout=program.layout).name
-            if leaf_bytes and wb == 32 and backend != "shard-words":
-                devices, chip_bytes = device_memory()
-                if shards_over_devices(devices, leaf_bytes, chip_bytes):
-                    backend = "shard-words"
-    spec = get_backend(backend)
-    if wb not in spec.layouts:
-        raise ValueError(
-            f"backend {backend!r} does not support the {wb}-bit plane "
-            f"layout (declares {sorted(spec.layouts)})")
-    # Cache on the resolved BackendSpec, not the name: re-registering a
-    # name creates a new (frozen, hashable) spec, so stale pipelines
-    # compiled by a replaced builder can never be served.
-    return _cached_pipeline(program, spec, interpret, donate)
+    name = fused_evaluator(program.layout, leaf_bytes, pinned=backend)
+    return _cached_pipeline(program, name, interpret, donate)
 
 
 @functools.lru_cache(maxsize=256)  # bounded: one jit callable per structure
-def _cached_pipeline(program: FusedProgram, spec, interpret: bool,
+def _cached_pipeline(program: FusedProgram, name: str, interpret: bool,
                      donate: bool):
-    return spec.builder(program, interpret=interpret, donate=donate)
+    build, _ = EVALUATORS[name]
+    return build(program, interpret=interpret, donate=donate)
 
 
 def with_fault_injection(pipeline, injector):

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512")
-
 """Multi-pod dry-run (deliverable e): lower + compile EVERY
 (architecture x input-shape) cell on the production meshes.
 
@@ -12,14 +8,16 @@ For each cell: .lower() -> .compile() must succeed; we record
 memory_analysis() (proves it fits), cost_analysis() (FLOPs/bytes for the
 roofline), and the collective-byte census parsed from the post-SPMD HLO.
 
-NOTE the XLA_FLAGS line ABOVE this docstring: it must execute before any
-jax import (device count locks on first backend init), and only in this
-entrypoint — tests and benches see the real single CPU device.
+Run as a program, it forces 512 host devices (``XLA_FLAGS``) before JAX
+starts its backend (the device count locks on first backend init).
+Importing the module sets nothing, so tests and benches that import
+``parse_collective_bytes`` see the real devices.
 """
 
 import argparse
 import dataclasses
 import json
+import os
 import re
 import time
 import traceback
@@ -249,4 +247,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=512")
     main()

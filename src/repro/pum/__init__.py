@@ -1,8 +1,7 @@
 """repro.pum — the public API of the PULSAR PuM compute stack.
 
 Everything an application needs is here; nothing else in the repo is a
-stable surface (``PulsarEngine``'s op methods survive only as a
-deprecated compat shim). Three pieces:
+stable surface. The pieces:
 
 * :class:`PumArray` — ndarray-like handle with operator overloading
   (``& | ^ + - * // % < > <= >=``, ``divmod()``, ``popcount()``,
@@ -11,9 +10,6 @@ deprecated compat shim). Three pieces:
 * :class:`Device` + :class:`EngineConfig` — configuration and lifecycle
   (``pum.device(...)`` as a context manager scopes the default device
   for ``pum.asarray`` and auto-flushes on exit);
-* the backend registry (:func:`register_backend` and friends) — the
-  sim-chip, word-domain-CPU and Pallas-TPU evaluators are selected by
-  capability lookup; new backends register additively;
 * telemetry (:func:`profile`, :class:`Tracer`, :class:`CounterBank`) —
   ``with pum.profile(path="trace.json"):`` traces fused flush phases to
   Chrome trace-event JSON and populates ``Device.counters``; zero
@@ -42,25 +38,22 @@ deprecated compat shim). Three pieces:
   and ``EngineStats`` stay bit-identical. See ``docs/autotuning.md``.
 
 See ``docs/api.md`` for the full surface, the Device lifecycle, the
-backend registry contract, and the old-call -> new-call migration table.
+fused evaluators and the rule that chooses among them
+(``repro.backends``), and the old-call -> new-call migration table.
 """
 
 from repro.autotune import TunedPlan, Tuner, WorkloadProfile
-from repro.backends import (BackendSpec, available_backends, get_backend,
-                            register_backend, select_backend,
-                            unregister_backend)
 from repro.core.engine import EngineStats, FlushHandle
 from repro.kernels.plane_layout import (LAYOUT32, LAYOUT64, PlaneLayout,
                                         get_layout)
-from repro.pum.api import (Device, PumArray, as_device, asarray,
-                           default_device, device, profile)
+from repro.pum.api import (Device, PumArray, asarray, default_device,
+                           device, profile)
 from repro.pum.capture import CapturedProgram
 from repro.pum.config import EngineConfig
 from repro.reliability import ReliabilityConfig, ReliabilityMap, calibrate
 from repro.telemetry import CounterBank, Tracer
 
 __all__ = [
-    "BackendSpec",
     "CapturedProgram",
     "CounterBank",
     "Device",
@@ -77,16 +70,10 @@ __all__ = [
     "TunedPlan",
     "Tuner",
     "WorkloadProfile",
-    "as_device",
     "asarray",
-    "available_backends",
     "calibrate",
     "default_device",
     "device",
-    "get_backend",
     "get_layout",
     "profile",
-    "register_backend",
-    "select_backend",
-    "unregister_backend",
 ]

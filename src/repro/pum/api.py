@@ -29,7 +29,7 @@ contract, now behind one type.
 from __future__ import annotations
 
 import contextlib
-import warnings
+import dataclasses
 
 import numpy as np
 
@@ -45,56 +45,25 @@ class Device:
     """One PuM compute device: an engine plus its configuration.
 
     Construction goes through :class:`EngineConfig` (keyword overrides
-    accepted); the eager dataplane and fused evaluators are resolved via
-    the ``repro.backends`` registry. As a context manager the device
-    becomes the scoped default for :func:`asarray` and flushes any
-    pending fused graph on exit.
+    accepted), handed whole to the engine; the fused evaluator a flush
+    runs on is ``repro.backends.fused_evaluator``'s choice. As a context
+    manager the device becomes the scoped default for :func:`asarray`
+    and flushes any pending fused graph on exit.
     """
 
-    def __init__(self, config: EngineConfig | None = None, *,
-                 _engine=None, **overrides):
+    def __init__(self, config: EngineConfig | None = None, **overrides):
         if config is None:
             config = EngineConfig(**overrides)
         elif overrides:
             config = config.replace(**overrides)
-        # The sim backend is per-op by construction (the chip model has no
-        # word dataplane to fuse over).
-        if config.backend == "sim" and config.fuse:
-            config = config.replace(fuse=False)
-        # Likewise when NO registered fused evaluator supports this
-        # width/layout pair (a pinned fused_backend that covers it takes
-        # precedence): fall back to per-op eager execution instead of
-        # refusing to build — EngineConfig-valid widths up to 64 always
-        # yield a working device — and say so, since the eager path
-        # computes on the host.
-        if config.fuse and config.fused_backend is None:
-            from repro.backends import select_backend
-            try:
-                select_backend(require="fused", width=config.width,
-                               layout=config.resolved_layout())
-            except LookupError as e:
-                warnings.warn(f"{e}; this device runs eager (fuse=False)",
-                              RuntimeWarning, stacklevel=3)
-                config = config.replace(fuse=False)
-        self.config = config
-        if _engine is None:
-            from repro.core.engine import PulsarEngine
-            _engine = PulsarEngine(
-                mfr=config.mfr, width=config.width,
-                row_bits=config.row_bits, banks=config.banks,
-                backend=config.backend, success_db=config.success_db,
-                use_pulsar=config.use_pulsar, chained=config.chained,
-                controller=config.controller, seed=config.seed,
-                fuse=config.fuse, flush_threshold=config.flush_threshold,
-                flush_memory_bytes=config.flush_memory_bytes,
-                donate_leaves=config.donate_leaves, layout=config.layout,
-                leaf_cache_bytes=config.leaf_cache_bytes,
-                fused_backend=config.fused_backend,
-                ref_postponing=config.ref_postponing,
-                reliability=config.reliability,
-                cmd_buffer_lookahead=config.cmd_buffer_lookahead)
-        self.engine = _engine
+        from repro.core.engine import PulsarEngine
+        self.engine = PulsarEngine(config)
         self._scalars: dict[tuple, np.ndarray] = {}
+
+    @property
+    def config(self) -> EngineConfig:
+        """The :class:`EngineConfig` this device's engine runs."""
+        return self.engine.config
 
     # -- array construction / lifecycle -------------------------------- #
 
@@ -207,7 +176,7 @@ class Device:
         if save is not None:
             rmap.save(save)
         if attach:
-            if inject and not self.engine.fuse:
+            if inject and not cfg.fuse:
                 raise ValueError(
                     "reliability fault injection hooks the fused dispatch "
                     "path; this device runs eager (fuse=False)")
@@ -217,7 +186,7 @@ class Device:
             # Planning/placement caches were computed without the map.
             self.engine._best_cfg_cache.clear()
             self.engine._batch_cache.clear()
-            self.config = cfg.replace(reliability=rcfg)
+            self.engine.config = cfg.replace(reliability=rcfg)
         return rmap
 
     def reset_stats(self) -> None:
@@ -266,7 +235,7 @@ class Device:
         """
         from repro.autotune import (OnlineAutotuner, Tuner,
                                     WorkloadProfile)
-        if not self.engine.fuse:
+        if not self.config.fuse:
             raise ValueError(
                 "autotune targets the fused execution pipeline; this "
                 "device runs eager (fuse=False)")
@@ -297,50 +266,44 @@ class Device:
     def _apply_plan(self, plan, *, cost_plane: bool = False,
                     flush: bool = True) -> None:
         """Reconfigure the live engine to a ``TunedPlan`` (the
-        ``calibrate()`` idiom: mutate the engine, drop stale caches,
-        replace ``self.config``). With ``flush=True`` pending graphs
+        ``calibrate()`` idiom: replace the engine's config, rebuild what
+        derives from it, drop stale caches). With ``flush=True`` pending graphs
         flush first so backend/layout flips never split a recorded
         program across lane formats; the online autotuner calls with
         ``flush=False`` from inside the flush path and the
         backend/layout switch is then deferred while graphs are
         pending."""
-        cfg = plan.apply(self.config, cost_plane=cost_plane)
         # A fuse flip cannot be applied to a live engine (it would
         # rebuild the whole execution pipeline mid-stream); the
         # recommendation stays on the returned plan for the caller to
         # construct a new device from.
-        if cfg.fuse != self.config.fuse:
-            cfg = cfg.replace(fuse=self.config.fuse)
+        old = self.config
+        plan = dataclasses.replace(plan, fuse=old.fuse)
+        cfg = plan.apply(old, cost_plane=cost_plane)
         eng = self.engine
         if flush:
             eng.flush_all()
         with eng._lock:
-            eng.flush_threshold = cfg.flush_threshold
-            eng.flush_memory_bytes = cfg.flush_memory_bytes
-            eng.cmd_buffer_lookahead = cfg.cmd_buffer_lookahead
             pending = bool(eng._inflight) or any(
                 g is not None and getattr(g, "ops", None)
                 for g in eng._slots.values())
             if pending:
-                cfg = cfg.replace(fused_backend=self.config.fused_backend,
-                                  layout=self.config.layout)
-            else:
-                eng.fused_backend = cfg.fused_backend
-                eng.layout = cfg.resolved_layout()
-            if cost_plane and cfg.ref_postponing != eng.ref_postponing \
+                cfg = cfg.replace(fused_backend=old.fused_backend,
+                                  layout=old.layout)
+            if cost_plane and cfg.ref_postponing != old.ref_postponing \
                     and cfg.controller == "auto":
                 from repro.controller import MemoryController
                 from repro.core.cost_model import CostModel as _EngineCost
                 eng.controller = MemoryController(
                     n_banks=cfg.banks, postponing=cfg.ref_postponing,
                     lookahead=cfg.cmd_buffer_lookahead)
-                eng.ref_postponing = cfg.ref_postponing
                 eng.cost = _EngineCost(row_bits=cfg.row_bits,
                                        controller=eng.controller)
+            eng.config = cfg
+            eng.layout = cfg.resolved_layout()
             # Planning/batch caches were computed under the old config.
             eng._best_cfg_cache.clear()
             eng._batch_cache.clear()
-        self.config = cfg
 
     @property
     def latency_ms(self) -> float:
@@ -348,7 +311,7 @@ class Device:
 
     @property
     def width(self) -> int:
-        return self.engine.width
+        return self.config.width
 
     @property
     def layout(self):
@@ -684,28 +647,3 @@ def profile(device: Device | None = None, path: str | None = None):
             dev.engine.tracer = prev
             if path is not None:
                 tracer.export(path, counters=dev.engine.counters)
-
-
-def as_device(obj) -> Device:
-    """Coerce to a :class:`Device`: passes Devices through and wraps an
-    existing ``PulsarEngine`` (compat for call sites that still construct
-    engines directly)."""
-    if isinstance(obj, Device):
-        return obj
-    from repro.core.engine import PulsarEngine
-    if isinstance(obj, PulsarEngine):
-        cfg = EngineConfig(
-            mfr=obj.mfr, width=obj.width, row_bits=obj.row_bits,
-            banks=obj.banks, backend=obj.backend, use_pulsar=obj.use_pulsar,
-            chained=obj.chained, controller=obj.controller, seed=obj.seed,
-            fuse=obj.fuse, flush_threshold=obj.flush_threshold,
-            flush_memory_bytes=obj.flush_memory_bytes,
-            donate_leaves=obj.donate_leaves, success_db=obj.db,
-            leaf_cache_bytes=obj.leaf_cache_bytes,
-            layout=obj.layout, fused_backend=obj.fused_backend,
-            ref_postponing=obj.ref_postponing,
-            reliability=(None if obj.reliability is None
-                         else obj.reliability.config),
-            cmd_buffer_lookahead=obj.cmd_buffer_lookahead)
-        return Device(cfg, _engine=obj)
-    raise TypeError(f"cannot interpret {type(obj).__name__} as a Device")

@@ -97,7 +97,7 @@ class CapturedProgram:
     """A function of PumArrays, compiled once per input-shape signature."""
 
     def __init__(self, device, fn, name: str | None = None):
-        if not device.engine.fuse:
+        if not device.config.fuse:
             raise ValueError(
                 "capture requires a fused device (fuse=True): an eager "
                 "device has no program to record")
@@ -218,7 +218,7 @@ class CapturedProgram:
         # Replays rebind the leaves, so the pipeline may never donate its
         # input buffers (the staged constants are reused every call).
         pipeline = get_pipeline(program, donate=False,
-                                backend=eng.fused_backend)
+                                backend=eng.config.fused_backend)
         by_leaf = {g._leaf_ids[id(a)]: i for i, a in enumerate(norm)
                    if id(a) in g._leaf_ids}
         pad = (-g.n) % 32

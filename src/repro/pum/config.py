@@ -1,16 +1,18 @@
 """EngineConfig — the frozen configuration object behind ``pum.device``.
 
-One immutable dataclass replaces ``PulsarEngine``'s keyword sprawl: every
-knob a device needs is named, validated once, and carried by the
-:class:`~repro.pum.Device` that owns the engine. ``dataclasses.replace``
-derives variants (the idiom the benchmarks use for PULSAR-vs-FracDRAM
-pairs).
+One immutable dataclass configures a device: every knob is named here,
+validated once (``__post_init__``), and handed whole to the
+:class:`~repro.core.engine.PulsarEngine` a :class:`~repro.pum.Device`
+owns. ``dataclasses.replace`` derives variants (the idiom the benchmarks
+use for PULSAR-vs-FracDRAM pairs).
 """
 
 from __future__ import annotations
 
 import dataclasses
 from typing import Any
+
+from repro.backends import DATAPLANES, fused_evaluator
 
 
 @dataclasses.dataclass(frozen=True)
@@ -20,28 +22,28 @@ class EngineConfig:
     Fields mirror the modeled hardware (``mfr``/``width``/``row_bits``/
     ``banks``), the cost plane (``use_pulsar``/``chained``/``controller``)
     and the execution pipeline (``backend``/``fuse``/auto-flush bounds/
-    ``donate_leaves``). Unlike the legacy engine constructor, ``fuse``
-    defaults to **True**: the fused lazy pipeline is the production path
-    (bit-exact and stats-identical to eager — set ``fuse=False`` to force
-    per-op eager execution).
+    ``donate_leaves``). ``fuse`` defaults to **True**: the fused lazy
+    pipeline is the production path (bit-exact and stats-identical to
+    eager — set ``fuse=False`` to force per-op eager execution).
 
-    * ``backend`` — eager-dataplane name resolved through the
-      ``repro.backends`` registry: ``"fast"`` (packed NumPy words) or
-      ``"sim"`` (bit-exact chip model; implies ``fuse=False``), or any
-      registered name with the ``"eager"`` capability.
+    * ``backend`` — the eager dataplane: ``"fast"`` (packed NumPy words)
+      or ``"sim"`` (bit-exact chip model; per-op by construction, so a
+      ``sim`` config always carries ``fuse=False``).
     * ``layout`` — plane-layout word bits of the fused dataplane (32 or
       64, or a ``repro.kernels.plane_layout.PlaneLayout``). ``None``
       derives the narrowest canonical layout holding ``width`` (32-bit
       up to width 32, 64-bit above); pass 64 explicitly to run narrow
       values on 64-bit lanes (e.g. to keep raw uint64 bitmaps unsplit).
-    * ``fused_backend`` — pin a registered fused evaluator by name (e.g.
-      ``"shard-words"``, the multi-device word-axis pipeline); ``None``
-      lets the capability lookup pick the best available one.
+    * ``fused_backend`` — pin a fused evaluator of
+      ``repro.backends.EVALUATORS`` by name (e.g. ``"shard-words"``, the
+      multi-device word-axis pipeline); it must run the resolved layout.
+      ``None`` leaves the choice to ``repro.backends.fused_evaluator``.
     * ``controller`` — ``None`` (closed-form bank divide), ``"auto"``
       (build a ``MemoryController``), or a controller instance.
     * ``ref_postponing`` — REF commands batched per rank lockout by the
       ``"auto"`` controller's refresher (1..8; JEDEC allows postponing up
       to 8): longer but rarer refresh windows, priced by ``batch_cost``.
+      A value other than 1 needs ``controller="auto"``.
     * ``cmd_buffer_lookahead`` — per-bank command-buffer depth of the
       concurrent-client crossbar (LiteDRAM's ``cmd_buffer_depth``): how
       many pending sequences each bank machine may hold when scheduling
@@ -91,6 +93,12 @@ class EngineConfig:
     cmd_buffer_lookahead: int = 8
 
     def __post_init__(self):
+        if self.backend not in DATAPLANES:
+            raise ValueError(f"backend must be 'fast' or 'sim', got "
+                             f"{self.backend!r}")
+        if self.backend == "sim" and self.fuse:
+            # The chip model has no word dataplane to fuse over.
+            object.__setattr__(self, "fuse", False)
         if not 1 <= self.width <= 64:
             raise ValueError(f"width must be in [1, 64], got {self.width}")
         if self.flush_threshold is not None and self.flush_threshold < 1:
@@ -103,10 +111,25 @@ class EngineConfig:
         if not 1 <= self.ref_postponing <= 8:
             raise ValueError("ref_postponing must be in [1, 8] (JEDEC "
                              "allows postponing up to 8 REFs)")
-        if self.resolved_layout().word_bits < self.width:
+        if self.ref_postponing != 1 and self.controller != "auto":
+            # Loud, not silently inert: the closed-form path never models
+            # refresh, and a prebuilt controller carries its own policy.
+            raise ValueError(
+                "ref_postponing requires controller='auto' (with "
+                "controller=None refresh is not modeled; a prebuilt "
+                "MemoryController sets postponing= itself)")
+        layout = self.resolved_layout()
+        if layout.word_bits < self.width:
             raise ValueError(
                 f"width {self.width} does not fit the "
-                f"{self.resolved_layout().word_bits}-bit plane layout")
+                f"{layout.word_bits}-bit plane layout")
+        if self.fused_backend is not None:
+            fused_evaluator(layout, pinned=self.fused_backend)
+        if getattr(self.reliability, "inject", False) and not self.fuse:
+            raise ValueError(
+                "reliability fault injection hooks the fused dispatch "
+                "path; it requires fuse=True (eager ops never run the "
+                "vote/retry loop)")
 
     def resolved_layout(self):
         """The :class:`~repro.kernels.plane_layout.PlaneLayout` this

@@ -41,7 +41,7 @@ def test_raw_heavy_workload_selects_64bit_layout():
     plan = Tuner().tune(profile_of(raw_fraction=1.0, lanes=8192.0), cfg)
     nd = plan.non_default(cfg)
     assert nd.get("word_bits") == 64
-    assert plan.fused_backend == "words-cpu-64"
+    assert (plan.fused_backend, plan.word_bits) == ("words-cpu", 64)
     assert plan.score_s < plan.baseline_score_s
 
 
@@ -96,12 +96,19 @@ def test_leaf_upload_bound_raw_chain_recommends_eager():
     assert Tuner().tune(warm, cfg).fuse is True
 
 
-def test_candidates_respect_registry_constraints():
-    cfg = pum.EngineConfig(width=48)  # only 64-bit-layout backends fit
-    for cand in Tuner().candidates(cfg):
+def test_candidates_respect_evaluator_layouts():
+    from repro.backends import EVALUATORS, host_evaluators
+    cfg = pum.EngineConfig(width=48)  # only the 64-bit layout fits
+    cands = Tuner().candidates(cfg)
+    assert cands
+    for cand in cands:
         assert cand.word_bits == 64
-        spec = pum.get_backend(cand.fused_backend)
-        assert spec.max_width >= 48 and 64 in spec.layouts
+        assert cand.fused_backend in host_evaluators()
+        assert 64 in EVALUATORS[cand.fused_backend][1]
+    # shard-words runs no 64-bit layout, even when asked for by name
+    space = SearchSpace(backends=("shard-words", "words-cpu"))
+    assert {c.fused_backend for c in Tuner(space=space).candidates(cfg)} \
+        == {"words-cpu"}
 
 
 def test_space_override_narrows_search():
@@ -201,32 +208,17 @@ def test_plan_schema_guard(tmp_path):
 
 def test_apply_splits_execution_and_cost_plane_knobs():
     cfg = pum.EngineConfig(width=32, controller="auto")
-    plan = TunedPlan(fused_backend="words-cpu-64", word_bits=64,
+    plan = TunedPlan(fused_backend="words-cpu", word_bits=64,
                      flush_threshold=4096, ref_postponing=8,
                      cmd_buffer_lookahead=32)
     exe = plan.apply(cfg)
-    assert exe.fused_backend == "words-cpu-64"
+    assert exe.fused_backend == "words-cpu"
     assert exe.resolved_layout().word_bits == 64
     assert exe.flush_threshold == 4096
     assert exe.cmd_buffer_lookahead == 32
     assert exe.ref_postponing == cfg.ref_postponing  # cost plane untouched
     full = plan.apply(cfg, cost_plane=True)
     assert full.ref_postponing == 8
-
-
-def test_selection_override_hook():
-    from repro.backends import get_selection_override, select_backend
-    plan = TunedPlan(fused_backend="words-cpu-64", word_bits=64)
-    assert get_selection_override("fused") is None
-    with plan.selection_override():
-        assert get_selection_override("fused") == "words-cpu-64"
-        # Satisfiable constraints: the pin wins over priority order.
-        assert select_backend(require="fused", width=16,
-                              layout=64).name == "words-cpu-64"
-        # Unsatisfiable constraints: normal lookup proceeds.
-        assert select_backend(require="fused", width=16,
-                              layout=32).name == "words-cpu"
-    assert get_selection_override("fused") is None
 
 
 # -- drift detection + online re-tune ----------------------------------- #

@@ -207,15 +207,17 @@ def test_batch_cost_speedup_bounded_and_cached():
 
 def test_engine_controller_pricing_adds_refresh_term():
     import numpy as np
-    from repro.core.engine import PulsarEngine
-    legacy = PulsarEngine(mfr="M", width=32, banks=16)
-    ctrl = PulsarEngine(mfr="M", width=32, banks=16, controller="auto")
+    import repro.pum as pum
+    legacy = pum.device(mfr="M", width=32, banks=16, fuse=False)
+    ctrl = pum.device(mfr="M", width=32, banks=16, controller="auto",
+                      fuse=False)
     a = np.arange(65536 * 4, dtype=np.uint64)
-    legacy.add(a, a)
-    ctrl.add(a, a)
+    legacy.asarray(a) + a
+    ctrl.asarray(a) + a
     assert legacy.stats.refresh_stall_ns == 0.0
     assert ctrl.stats.refresh_stall_ns > 0.0
     # Scheduled pricing can only be slower than the ideal closed-form divide.
     assert ctrl.stats.latency_ns >= legacy.stats.latency_ns
     # Dataplane results are unaffected by the cost plane.
-    np.testing.assert_array_equal(legacy.add(a, a), ctrl.add(a, a))
+    np.testing.assert_array_equal(np.asarray(legacy.asarray(a) + a),
+                                  np.asarray(ctrl.asarray(a) + a))

@@ -13,7 +13,6 @@ from repro.core.destruction import (destroy_bank_fracdram,
                                     plan_pulsar_cover,
                                     pulsar_destruction_cost,
                                     rowclone_destruction_cost)
-from repro.core.engine import PulsarEngine
 from repro.core.geometry import DramGeometry
 import repro.pum as pum
 from repro.core.profiles import MFR_H, MFR_M
@@ -149,34 +148,36 @@ def test_plan_pulsar_cover_counts():
 # --------------------------------------------------------------------- #
 
 def test_engine_dataplane_matches_numpy():
-    eng = PulsarEngine(mfr="M", width=16, backend="fast")
+    eng = pum.device(mfr="M", width=16, backend="fast", fuse=False)
     rng = np.random.default_rng(0)
     a = rng.integers(0, 2**16, 512, dtype=np.uint64)
     b = rng.integers(1, 2**16, 512, dtype=np.uint64)
-    np.testing.assert_array_equal(eng.and_(a, b), a & b)
-    np.testing.assert_array_equal(eng.add(a, b), (a + b) & 0xFFFF)
-    np.testing.assert_array_equal(eng.mul(a, b), (a * b) & 0xFFFF)
-    np.testing.assert_array_equal(eng.div(a, b), a // b)
+    x = eng.asarray(a)
+    np.testing.assert_array_equal(np.asarray(x & b), a & b)
+    np.testing.assert_array_equal(np.asarray(x + b), (a + b) & 0xFFFF)
+    np.testing.assert_array_equal(np.asarray(x * b), (a * b) & 0xFFFF)
+    np.testing.assert_array_equal(np.asarray(x // b), a // b)
     assert eng.stats.latency_ns > 0
     assert 0 < eng.stats.lane_efficiency <= 1
 
 
 def test_engine_sim_backend_small():
-    eng = PulsarEngine(mfr="H", width=8, backend="sim")
+    eng = pum.device(mfr="H", width=8, backend="sim")
     rng = np.random.default_rng(1)
-    n = eng._alu.words * 32
+    n = eng.engine._alu.words * 32
     a = rng.integers(0, 256, n, dtype=np.uint64)
     b = rng.integers(0, 256, n, dtype=np.uint64)
-    np.testing.assert_array_equal(eng.and_(a, b), a & b)
-    np.testing.assert_array_equal(eng.add(a, b), (a + b) & 0xFF)
+    x = eng.asarray(a)
+    np.testing.assert_array_equal(np.asarray(x & b), a & b)
+    np.testing.assert_array_equal(np.asarray(x + b), (a + b) & 0xFF)
 
 
 def test_engine_pulsar_beats_fracdram_on_add():
-    pulsar = PulsarEngine(mfr="M", width=32, use_pulsar=True)
-    frac = PulsarEngine(mfr="M", width=32, use_pulsar=False)
+    pulsar = pum.device(mfr="M", width=32, use_pulsar=True, fuse=False)
+    frac = pum.device(mfr="M", width=32, use_pulsar=False, fuse=False)
     a = np.arange(65536, dtype=np.uint64)
-    pulsar.add(a, a)
-    frac.add(a, a)
+    pulsar.asarray(a) + a
+    frac.asarray(a) + a
     t_p = pulsar.stats.latency_ns / pulsar.stats.lane_efficiency
     t_f = frac.stats.latency_ns / frac.stats.lane_efficiency
     assert t_p < t_f  # the paper's headline performance claim

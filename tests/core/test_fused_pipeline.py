@@ -1,5 +1,9 @@
-"""Fused dataplane (engine fuse=True) vs eager: bit-exactness and
-cost-plane invariance, across widths and random op sequences."""
+"""Fused dataplane (fuse=True) vs eager: bit-exactness and cost-plane
+invariance, across widths and random op sequences. Ops go through
+``PumArray`` operators; the tests read the engine's recorded graph and
+``LazyArray`` handles (``x._data``) where the contract is about them."""
+
+import operator
 
 import numpy as np
 import pytest
@@ -10,14 +14,14 @@ except ModuleNotFoundError:  # optional dep: fixed-seed fallback
     from repro.testing import given, settings, st
 
 import repro.pum as pum
-from repro.core.engine import (LazyArray, PulsarEngine, _buffer_nbytes,
-                               _unpack_output, _vec_popcount)
+from repro.core.engine import (LazyArray, _buffer_nbytes, _unpack_output,
+                               _vec_popcount)
 from repro.kernels import fused_program
 from repro.kernels.plane_layout import LAYOUT32, LAYOUT64
 
 pytestmark = pytest.mark.fused
 
-# Chain ops: (engine method, n_operands). Applied as t = op(t, pool[i]).
+# Chain ops, applied as t = op(t, pool[i]).
 _CHAIN_OPS = ["and", "or", "xor", "add", "sub", "mul", "div", "mod"]
 _TAIL_OPS = ["less", "popcount", "reduce_and", "reduce_or", "reduce_xor"]
 
@@ -28,39 +32,36 @@ def _rand_inputs(width, n, seed):
             for _ in range(3)]
 
 
-def _apply(e, name, t, other):
-    if name == "and":
-        return e.and_(t, other)
-    if name == "or":
-        return e.or_(t, other)
-    if name == "xor":
-        return e.xor(t, other)
-    if name == "add":
-        return e.add(t, other)
-    if name == "sub":
-        return e.sub(t, other)
-    if name == "mul":
-        return e.mul(t, other)
-    if name == "div":
-        return e.div(t, other)
-    if name == "mod":
-        return e.mod(t, other)
-    if name == "less":
-        return e.less_than(t, other)
+_BINARY = {"and": operator.and_, "or": operator.or_, "xor": operator.xor,
+           "add": operator.add, "sub": operator.sub, "mul": operator.mul,
+           "div": operator.floordiv, "mod": operator.mod,
+           "less": operator.lt}
+
+
+def _dev(**kw):
+    """A device; eager unless ``fuse=True`` is asked for."""
+    kw.setdefault("fuse", False)
+    return pum.device(**kw)
+
+
+def _apply(dev, name, t, other):
+    x = dev.asarray(t)
+    if name in _BINARY:
+        return _BINARY[name](x, other)
     if name == "popcount":
-        return e.popcount(t)
+        return x.popcount()
     if name.startswith("reduce_"):
-        return e.reduce_bits(t, name.removeprefix("reduce_"))
+        return x.reduce_bits(name.removeprefix("reduce_"))
     raise KeyError(name)
 
 
-def _run_sequence(e, inputs, op_seq):
+def _run_sequence(dev, inputs, op_seq):
     """Random chain over the input pool; returns every intermediate (so
     flush must materialize intermediates whose handles stay alive)."""
     outs = []
     t = inputs[0]
     for i, name in enumerate(op_seq):
-        t = _apply(e, name, t, inputs[(i + 1) % len(inputs)])
+        t = _apply(dev, name, t, inputs[(i + 1) % len(inputs)])
         outs.append(t)
     return [np.asarray(o, np.uint64) for o in outs]
 
@@ -75,8 +76,8 @@ def test_fused_matches_eager_random_sequence(width, seed):
     op_seq = [str(rng.choice(_CHAIN_OPS)) for _ in range(n_ops - 1)]
     op_seq.append(str(rng.choice(_CHAIN_OPS + _TAIL_OPS)))
 
-    eager = PulsarEngine(width=width)
-    fused = PulsarEngine(width=width, fuse=True)
+    eager = _dev(width=width)
+    fused = _dev(width=width, fuse=True)
     want = _run_sequence(eager, inputs, op_seq)
     got = _run_sequence(fused, inputs, op_seq)
     for w, g in zip(want, got):
@@ -89,13 +90,13 @@ def test_fused_all_opcodes_bit_exact(width):
     inputs = _rand_inputs(width, 256, seed=width)
     seq = ["and", "xor", "or", "add", "sub", "mul", "div", "mod", "less"]
     tails = ["popcount", "reduce_and", "reduce_or", "reduce_xor"]
-    eager = PulsarEngine(width=width)
-    fused = PulsarEngine(width=width, fuse=True)
+    eager = _dev(width=width)
+    fused = _dev(width=width, fuse=True)
 
-    def run(e):
-        outs = _run_sequence(e, inputs, seq)
-        base = e.add(inputs[0], inputs[1])
-        outs += [np.asarray(_apply(e, t, base, None), np.uint64)
+    def run(d):
+        outs = _run_sequence(d, inputs, seq)
+        base = d.asarray(inputs[0]) + inputs[1]
+        outs += [np.asarray(_apply(d, t, base, None), np.uint64)
                  for t in tails]
         return outs
 
@@ -109,8 +110,8 @@ def test_cost_plane_invariance_with_controller():
     (latency, energy, sequences, refresh stalls)."""
     inputs = _rand_inputs(32, 128, seed=3)
     seq = ["add", "xor", "sub", "and", "popcount"]
-    eager = PulsarEngine(width=32, controller="auto")
-    fused = PulsarEngine(width=32, controller="auto", fuse=True)
+    eager = _dev(width=32, controller="auto")
+    fused = _dev(width=32, controller="auto", fuse=True)
     for w, g in zip(_run_sequence(eager, inputs, seq),
                     _run_sequence(fused, inputs, seq)):
         np.testing.assert_array_equal(w, g)
@@ -121,9 +122,9 @@ def test_cost_plane_invariance_with_controller():
 def test_charges_accrue_at_record_time():
     """The cost plane must not wait for flush(): recording IS charging."""
     import dataclasses
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = _rand_inputs(32, 64, seed=5)[0]
-    t = e.add(a, a)
+    t = e.asarray(a) + a
     assert e.stats.latency_ns > 0 and e.stats.n_sequences > 0
     before = dataclasses.replace(e.stats)
     _ = np.asarray(t)  # flush: dataplane only
@@ -131,9 +132,9 @@ def test_charges_accrue_at_record_time():
 
 
 def test_lazy_array_api_and_flush():
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = _rand_inputs(32, 64, seed=7)[0]
-    t = e.xor(a, a)
+    t = (e.asarray(a) ^ a)._data
     assert isinstance(t, LazyArray)
     assert t.shape == (64,) and t.size == 64 and t.ndim == 1
     assert t.dtype == np.uint64
@@ -147,15 +148,15 @@ def test_lazy_array_api_and_flush():
 def test_lazy_array_eq_and_bool_follow_ndarray_semantics():
     """`==` must compare values (not identity) and truth-testing must
     behave like ndarray — no silent scalars from ported eager code."""
-    e = PulsarEngine(fuse=True)
-    z = np.arange(4, dtype=np.uint64)
-    t1 = e.add(z, z)
-    t2 = e.add(z, z)
+    e = _dev(fuse=True)
+    z = e.asarray(np.arange(4, dtype=np.uint64))
+    t1 = (z + z)._data
+    t2 = (z + z)._data
     np.testing.assert_array_equal(t1 == t2, np.full(4, True))
     np.testing.assert_array_equal(t1 != t2, np.full(4, False))
     with pytest.raises(ValueError):  # ambiguous, exactly like ndarray
-        bool(e.add(z, z))
-    one = e.add(np.ones(1, np.uint64), np.zeros(1, np.uint64))
+        bool((z + z)._data)
+    one = (e.asarray(np.ones(1, np.uint64)) + np.zeros(1, np.uint64))._data
     assert bool(one)
 
 
@@ -165,26 +166,28 @@ def test_mul_div_stay_inside_the_fused_flush():
     materialization) and still matches eager bit-exactly with identical
     stats."""
     inputs = _rand_inputs(16, 96, seed=11)
-    eager = PulsarEngine(width=16)
-    fused = PulsarEngine(width=16, fuse=True)
+    eager = _dev(width=16)
+    fused = _dev(width=16, fuse=True)
 
-    def run(e):
-        t = e.add(inputs[0], inputs[2])
-        m = e.mul(t, inputs[1])
-        d = e.div(m, inputs[1])
-        r = e.mod(m, inputs[1])
-        s = e.sub(d, t)
+    def run(dev):
+        t = dev.asarray(inputs[0]) + inputs[2]
+        m = t * inputs[1]
+        d = m // inputs[1]
+        r = m % inputs[1]
+        s = d - t
         return (t, m, d, r, s)
 
     want = [np.asarray(x, np.uint64) for x in run(eager)]
     got = run(fused)
     # No eager fallback: every handle is still pending before the flush.
-    assert all(isinstance(x, LazyArray) and x._value is None for x in got)
+    assert all(isinstance(x._data, LazyArray) and x._data._value is None
+               for x in got)
     # add + mul + sub = 3 ops; div and mod each lower to the shared
     # divmod tuple op plus a selector (2 ops each) — flush-time CSE
     # unifies the two divmods into ONE restoring-division pass.
-    assert fused._graph is not None and len(fused._graph.ops) == 7
-    opcodes = [op for op, _, _ in fused._graph.ops]
+    g = fused.engine._graph
+    assert g is not None and len(g.ops) == 7
+    opcodes = [op for op, _, _ in g.ops]
     assert opcodes.count("divmod") == 2  # unified to 1 by optimize_program
     for w, g in zip(want, got):
         np.testing.assert_array_equal(w, np.asarray(g, np.uint64))
@@ -204,22 +207,22 @@ def test_fused_mul_div_property(width, seed):
     edges = np.array([0, 1, 1 << (width - 1), (1 << width) - 1], np.uint64)
     a[:4], b[:4] = edges, edges[::-1]
     b[::5] = 0  # div/mod by zero -> 0, the unsigned NumPy semantics
-    eager = PulsarEngine(width=width)
-    fused = PulsarEngine(width=width, fuse=True)
+    eager = _dev(width=width)
+    fused = _dev(width=width, fuse=True)
     for op in ("mul", "div", "mod"):
-        w = np.asarray(getattr(eager, op)(a, b), np.uint64)
-        g = getattr(fused, op)(a, b)
-        assert isinstance(g, LazyArray)
+        w = np.asarray(_apply(eager, op, a, b), np.uint64)
+        g = _apply(fused, op, a, b)
+        assert isinstance(g._data, LazyArray)
         np.testing.assert_array_equal(w, np.asarray(g, np.uint64))
     assert eager.stats == fused.stats
 
 
 def test_graph_splits_on_element_count_change():
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = _rand_inputs(32, 64, seed=13)[0]
     b = _rand_inputs(32, 128, seed=14)[0]
-    x = e.add(a, a)
-    y = e.add(b, b)  # different n: previous graph flushes
+    x = e.asarray(a) + a
+    y = e.asarray(b) + b  # different n: previous graph flushes
     np.testing.assert_array_equal(np.asarray(x),
                                   (a + a) & np.uint64(0xFFFFFFFF))
     np.testing.assert_array_equal(np.asarray(y),
@@ -227,11 +230,11 @@ def test_graph_splits_on_element_count_change():
 
 
 def test_dead_handles_are_dead_code():
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = _rand_inputs(32, 64, seed=17)[0]
-    tmp = e.and_(a, a)
-    tmp = e.xor(tmp, a)  # first AND's handle dies here
-    keep = e.add(tmp, a)
+    tmp = e.asarray(a) & a
+    tmp = tmp ^ a  # first AND's handle dies here
+    keep = tmp + a
     del tmp
     lat = e.stats.latency_ns  # dead ops were still charged
     e.flush()
@@ -242,12 +245,12 @@ def test_dead_handles_are_dead_code():
 
 def test_pipeline_cache_reuses_compiled_programs():
     """Same graph structure across batches -> one compiled pipeline."""
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
 
     def batch(seed):
         a, b, c = _rand_inputs(32, 256, seed)
-        t = e.and_(a, b)
-        t = e.add(t, c)
+        t = e.asarray(a) & b
+        t = t + c
         return np.asarray(t)
 
     batch(0)
@@ -260,24 +263,27 @@ def test_pipeline_cache_reuses_compiled_programs():
 
 
 def test_fuse_requires_fast_backend():
-    with pytest.raises(ValueError):
-        PulsarEngine(backend="sim", fuse=True)
+    """The sim chip model has no word dataplane to fuse over: asking for
+    both runs per-op, in the config the engine is built from."""
+    dev = _dev(backend="sim", fuse=True)
+    assert not dev.config.fuse and not dev.engine.config.fuse
+    assert pum.EngineConfig(backend="sim").fuse is False
 
 
 def test_fused_arithmetic_rejects_out_of_width_operands():
     """Eager arithmetic computes on raw uint64 values; fused computes
     modulo 2**width. Out-of-range operands to arithmetic ops must fail
     loudly, not silently truncate into different answers."""
-    e = PulsarEngine(width=8, fuse=True)
+    e = _dev(width=8, fuse=True)
     big = np.array([256, 1], np.uint64)
     one = np.array([1, 1], np.uint64)
-    for op in (e.add, e.sub, e.mul, e.div, e.mod, e.less_than):
+    for op in ("add", "sub", "mul", "div", "mod", "less"):
         with pytest.raises(ValueError, match="modulo"):
-            op(big, one)
+            _apply(e, op, big, one)
     # popcount is the exception: out-of-width operands route through the
     # raw planewise graph (like and/or/xor) and the materialize fold sums
     # the per-lane counts — bit-exact with eager's raw-word popcount.
-    np.testing.assert_array_equal(np.asarray(e.popcount(big)),
+    np.testing.assert_array_equal(np.asarray(e.asarray(big).popcount()),
                                   np.array([1, 1], np.uint64))
 
 
@@ -291,9 +297,9 @@ def test_fused_raw_popcount_folds_lane_counts():
     b = rng.integers(0, 2**64, 257, dtype=np.uint64)
     want = _vec_popcount(a & b)
     for fuse in (False, True):
-        e = PulsarEngine(width=32, fuse=fuse)
-        pc = e._popcount(e._and(a, b), width=64)
-        composed = np.asarray(e._mul(pc, np.full_like(a, 2)), np.uint64)
+        e = _dev(width=32, fuse=fuse)
+        pc = (e.asarray(a) & b).popcount(width=64)
+        composed = np.asarray(pc * np.full_like(a, 2), np.uint64)
         np.testing.assert_array_equal(np.asarray(pc, np.uint64), want)
         np.testing.assert_array_equal(composed, want * 2)
 
@@ -380,20 +386,21 @@ def test_fused_planewise_raw_bitmap_path():
     b = rng.integers(0, 2**64, 65, dtype=np.uint64)
     c = rng.integers(0, 2**64, 65, dtype=np.uint64)
     for width in (8, 32):
-        eager = PulsarEngine(width=width)
-        fused = PulsarEngine(width=width, fuse=True)
+        eager = _dev(width=width)
+        fused = _dev(width=width, fuse=True)
 
-        def chain(e):
-            t = e.and_(a, b)
-            t = e.xor(t, c)
-            return e.or_(t, b)
+        def chain(dev):
+            t = dev.asarray(a) & b
+            t = t ^ c
+            return t | b
 
         want = np.asarray(chain(eager), np.uint64)
         got = chain(fused)
-        assert isinstance(got, LazyArray)
+        assert isinstance(got._data, LazyArray)
         # one raw graph, no flush between the three plane-wise ops
-        assert fused._graph is not None and fused._graph.raw
-        assert len(fused._graph.ops) == 3
+        g = fused.engine._graph
+        assert g is not None and g.raw
+        assert len(g.ops) == 3
         np.testing.assert_array_equal(want, np.asarray(got, np.uint64))
         assert eager.stats == fused.stats  # charged on words, not lanes
 
@@ -405,14 +412,14 @@ def test_raw_and_value_graphs_do_not_mix():
     rng = np.random.default_rng(33)
     bm = rng.integers(1 << 40, 2**64, 64, dtype=np.uint64)
     small = rng.integers(0, 256, 64, dtype=np.uint64)
-    e = PulsarEngine(width=32, fuse=True)
-    raw = e.and_(bm, bm)          # raw graph opens
-    assert e._graph.raw
-    t = e.add(small, small)       # value-mode: raw graph flushed first
-    assert raw._value is not None and not e._graph.raw
+    e = _dev(width=32, fuse=True)
+    raw = e.asarray(bm) & bm      # raw graph opens
+    assert e.engine._graph.raw
+    t = e.asarray(small) + small  # value-mode: raw graph flushed first
+    assert raw._data._value is not None and not e.engine._graph.raw
     np.testing.assert_array_equal(np.asarray(raw), bm)
     with pytest.raises(ValueError, match="modulo"):
-        e.add(e.and_(bm, bm), small)  # arithmetic on raw values: loud
+        (e.asarray(bm) & bm) + small  # arithmetic on raw values: loud
     np.testing.assert_array_equal(np.asarray(t), 2 * small)
 
 
@@ -420,11 +427,11 @@ def test_temporary_operands_do_not_collide():
     """id()-keyed leaf dedup must pin operands: freed temporaries whose
     addresses get reused by later operands must not resolve to a stale
     leaf snapshot."""
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     outs = []
     for k in range(8):
         tmp = np.full(64, k, np.uint64)  # dies each iteration
-        outs.append(e.add(tmp, tmp))
+        outs.append(e.asarray(tmp) + tmp)
         del tmp
     for k, o in enumerate(outs):
         np.testing.assert_array_equal(np.asarray(o),
@@ -432,20 +439,21 @@ def test_temporary_operands_do_not_collide():
 
 
 def test_materialized_handles_release_the_graph():
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = np.arange(64, dtype=np.uint64)
-    t = e.add(a, a)
-    assert any(p is a for p in e._graph._pins)  # id() key held alive
+    t = e.asarray(a) + a
+    assert any(p is a for p in e.engine._graph._pins)  # id() key held
     np.testing.assert_array_equal(np.asarray(t), 2 * a)
-    assert t._graph is None and t._engine is None  # snapshots reclaimable
+    # snapshots reclaimable
+    assert t._data._graph is None and t._data._engine is None
 
 
 def test_operand_mutation_after_record_does_not_alias():
     """The graph snapshots operands at record time: mutating the caller's
     buffer before flush must not change the result (eager parity)."""
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     b = np.arange(64, dtype=np.uint64)
-    t = e.add(b, b)
+    t = e.asarray(b) + b
     b[:] = 0
     np.testing.assert_array_equal(np.asarray(t),
                                   2 * np.arange(64, dtype=np.uint64))
@@ -454,11 +462,11 @@ def test_operand_mutation_after_record_does_not_alias():
 def test_operand_mutation_between_uses_registers_fresh_leaf():
     """Re-feeding the same buffer after an in-place mutation must see the
     new content (eager parity), not dedup to the stale snapshot."""
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = np.zeros(64, dtype=np.uint64)
-    t1 = e.add(a, a)
+    t1 = e.asarray(a) + a
     a[:] = 5
-    t2 = e.add(a, a)
+    t2 = e.asarray(a) + a
     np.testing.assert_array_equal(np.asarray(t1), np.zeros(64, np.uint64))
     np.testing.assert_array_equal(np.asarray(t2),
                                   np.full(64, 10, np.uint64))
@@ -468,9 +476,9 @@ def test_flush_failure_keeps_handles_recoverable(monkeypatch):
     """A transient pipeline failure must not orphan pending handles: the
     graph is restored and a later materialize retries."""
     from repro.core import engine as engine_mod
-    e = PulsarEngine(fuse=True)
+    e = _dev(fuse=True)
     a = np.arange(64, dtype=np.uint64)
-    t = e.add(a, a)
+    t = (e.asarray(a) + a)._data
 
     def boom(*args, **kw):
         raise RuntimeError("transient backend failure")
@@ -484,13 +492,13 @@ def test_flush_failure_keeps_handles_recoverable(monkeypatch):
 
 
 def test_pending_lazy_crosses_engines_via_materialization():
-    """A pending handle from one engine fed into another fused engine must
+    """A pending handle from one device fed into another fused device must
     materialize through its own engine, not alias the foreign graph."""
     a = _rand_inputs(32, 64, seed=29)[0]
-    e1 = PulsarEngine(fuse=True)
-    e2 = PulsarEngine(fuse=True)
-    t = e1.add(a, a)
-    r = e2.xor(t, a)
+    e1 = _dev(fuse=True)
+    e2 = _dev(fuse=True)
+    t = e1.asarray(a) + a
+    r = e2.asarray(a) ^ t
     np.testing.assert_array_equal(
         np.asarray(r), (((a + a) & np.uint64(0xFFFFFFFF)) ^ a))
 
@@ -507,15 +515,16 @@ def test_cse_does_not_change_results_or_stats():
     rng = np.random.default_rng(41)
     a = rng.integers(0, 1 << 16, 128, dtype=np.uint64)
     b = rng.integers(0, 1 << 16, 128, dtype=np.uint64)
-    eager = PulsarEngine(width=16)
-    fused = PulsarEngine(width=16, fuse=True)
+    eager = _dev(width=16)
+    fused = _dev(width=16, fuse=True)
 
-    def run(e):
-        t1 = e.add(a, b)
-        t2 = e.add(b, a)       # commutative duplicate of t1
-        t3 = e.xor(t1, t2)     # == 0
-        t4 = e.mul(t1, t1)
-        t5 = e.mul(t2, t2)     # duplicate of t4 after t1/t2 unify
+    def run(dev):
+        x, y = dev.asarray(a), dev.asarray(b)
+        t1 = x + y
+        t2 = y + x             # commutative duplicate of t1
+        t3 = t1 ^ t2           # == 0
+        t4 = t1 * t1
+        t5 = t2 * t2           # duplicate of t4 after t1/t2 unify
         return [np.asarray(x, np.uint64) for x in (t1, t2, t3, t4, t5)]
 
     for w, g in zip(run(eager), run(fused)):
@@ -527,17 +536,18 @@ def test_cse_normalized_programs_share_the_pipeline_cache():
     """Two recordings that differ only in redundant ops must normalize to
     the same program and hit the same compiled pipeline."""
     from repro.kernels import fused_program
-    e = PulsarEngine(width=32, fuse=True)
+    e = _dev(width=32, fuse=True)
     a, b, _ = _rand_inputs(32, 256, seed=43)
+    x = e.asarray(a)
 
-    t = e.and_(a, b)
-    keep = e.add(t, a)
+    t = x & b
+    keep = t + a
     np.asarray(keep)
     info = fused_program._cached_pipeline.cache_info()
 
-    t = e.and_(a, b)
-    dup = e.and_(a, b)     # live redundant twin: unified by CSE at flush
-    keep = e.add(t, a)
+    t = x & b
+    dup = x & b            # live redundant twin: unified by CSE at flush
+    keep = t + a
     np.asarray(keep)
     after = fused_program._cached_pipeline.cache_info()
     assert after.currsize == info.currsize  # no new compiled pipeline
@@ -549,10 +559,10 @@ def test_cse_normalized_programs_share_the_pipeline_cache():
 def test_optimizer_prunes_dead_leaves_from_the_pipeline():
     """An op whose handle dies pulls its exclusive leaves out of the
     compiled program too (fewer pipeline inputs, same results)."""
-    e = PulsarEngine(width=32, fuse=True)
+    e = _dev(width=32, fuse=True)
     a, b, c = _rand_inputs(32, 64, seed=47)
-    keep = e.add(a, b)
-    dead = e.xor(c, c)     # only consumer of leaf c
+    keep = e.asarray(a) + b
+    dead = e.asarray(c) ^ c  # only consumer of leaf c
     del dead
     np.testing.assert_array_equal(
         np.asarray(keep), (a + b) & np.uint64(0xFFFFFFFF))
@@ -568,19 +578,20 @@ def test_autoflush_graph_size_threshold():
     bound flushes (its handle materializes eagerly), and recording then
     continues into a fresh graph — results and stats unchanged."""
     a, b, c = _rand_inputs(16, 64, seed=51)
-    eager = PulsarEngine(width=16)
-    fused = PulsarEngine(width=16, fuse=True, flush_threshold=3)
+    eager = _dev(width=16)
+    fused = _dev(width=16, fuse=True, flush_threshold=3)
 
-    def run(e):
-        t = e.add(a, b)
-        t = e.xor(t, c)
-        t = e.mul(t, b)    # fused: auto-flush fires here
-        t = e.sub(t, a)
-        t = e.or_(t, c)
+    def run(dev):
+        t = dev.asarray(a) + b
+        t = t ^ c
+        t = t * b          # fused: auto-flush fires here
+        t = t - a
+        t = t | c
         return t
 
     got = run(fused)
-    assert fused._graph is not None and len(fused._graph.ops) == 2
+    g = fused.engine._graph
+    assert g is not None and len(g.ops) == 2
     want = run(eager)
     np.testing.assert_array_equal(np.asarray(want, np.uint64),
                                   np.asarray(got, np.uint64))
@@ -588,12 +599,12 @@ def test_autoflush_graph_size_threshold():
 
 
 def test_autoflush_memory_threshold():
-    e = PulsarEngine(width=32, fuse=True, flush_memory_bytes=4 * 64 * 4)
+    e = _dev(width=32, fuse=True, flush_memory_bytes=4 * 64 * 4)
     a, b, _ = _rand_inputs(32, 64, seed=53)
-    t = e.add(a, b)        # 2 leaves + 1 op = 3 held values: under bound
-    assert e._graph is not None
-    t2 = e.add(t, t)       # 4 held values * 4B * 64 lanes: bound reached
-    assert e._graph is None and t2._value is not None
+    t = e.asarray(a) + b   # 2 leaves + 1 op = 3 held values: under bound
+    assert e.engine._graph is not None
+    t2 = t + t             # 4 held values * 4B * 64 lanes: bound reached
+    assert e.engine._graph is None and t2._data._value is not None
     np.testing.assert_array_equal(
         np.asarray(t2), (2 * ((a + b) & np.uint64(0xFFFFFFFF)))
         & np.uint64(0xFFFFFFFF))
@@ -607,15 +618,15 @@ def test_autoflush_vmem_block_bound(width):
     from repro.telemetry import Tracer
     rng = np.random.default_rng(57)
     pool = rng.integers(0, 1 << min(width, 63), (1200, 32), dtype=np.uint64)
-    eager = PulsarEngine(width=width)
-    fused = PulsarEngine(width=width, fuse=True, flush_threshold=None,
-                         flush_memory_bytes=None)
-    fused.tracer = Tracer()
+    eager = _dev(width=width)
+    fused = _dev(width=width, fuse=True, flush_threshold=None,
+                 flush_memory_bytes=None)
+    fused.engine.tracer = Tracer()
 
-    def run(e):
-        t = pool[0]
+    def run(dev):
+        t = dev.asarray(pool[0])
         for x in pool[1:]:
-            t = e.xor(t, x)
+            t = t ^ x
         return np.asarray(t, np.uint64)
 
     np.testing.assert_array_equal(run(eager), run(fused))
@@ -660,13 +671,13 @@ def test_word_pipeline_numpy_short_circuits_only_on_cpu(monkeypatch, div):
 
 
 def test_autoflush_disabled_with_none():
-    e = PulsarEngine(width=16, fuse=True, flush_threshold=None,
-                     flush_memory_bytes=None)
+    e = _dev(width=16, fuse=True, flush_threshold=None,
+             flush_memory_bytes=None)
     a, b, _ = _rand_inputs(16, 64, seed=55)
-    t = a
+    t = e.asarray(a)
     for _ in range(64):
-        t = e.add(t, b)
-    assert e._graph is not None and len(e._graph.ops) == 64
+        t = t + b
+    assert e.engine._graph is not None and len(e.engine._graph.ops) == 64
 
 
 # --------------------------------------------------------------------- #
@@ -699,9 +710,10 @@ def test_swar_popcount_edge_values():
 def test_engine_popcount_small_arrays_use_swar():
     """The old per-element ``bin(int(x))`` path for size<4096 is gone; the
     vector path must be exact at every size."""
-    e = PulsarEngine(width=32)
+    e = _dev(width=32)
     rng = np.random.default_rng(23)
     for n in (1, 31, 33, 4095, 5000):
         a = rng.integers(0, 2**32, n, dtype=np.uint64)
         want = np.array([bin(int(x)).count("1") for x in a], np.uint64)
-        np.testing.assert_array_equal(np.asarray(e.popcount(a)), want)
+        np.testing.assert_array_equal(np.asarray(e.asarray(a).popcount()),
+                                      want)

@@ -259,7 +259,7 @@ def test_fused_pipeline_end_to_end():
     vals, _ = _fused_demo_stacks(512, seed=2)
     leaves = [jnp.asarray(v.astype(np.uint32).view(np.int32)) for v in vals]
     outs = get_pipeline(_FUSED_DEMO)(*leaves)
-    vert = get_pipeline(_FUSED_DEMO, force_vertical=True)(*leaves)
+    vert = get_pipeline(_FUSED_DEMO, backend="ref-vertical")(*leaves)
     for got, gvert, want in zip(outs, vert, _fused_demo_oracle(vals)):
         np.testing.assert_array_equal(
             np.asarray(got).view(np.uint32).astype(np.uint64), want)
@@ -361,7 +361,7 @@ def test_fused_program_mul_div_mod_all_evaluators():
     leaves = [jnp.asarray(v.astype(np.uint32).view(np.int32))
               for v in (a, b)]
     word = get_pipeline(_ARITH_DEMO)(*leaves)
-    vert = get_pipeline(_ARITH_DEMO, force_vertical=True)(*leaves)
+    vert = get_pipeline(_ARITH_DEMO, backend="ref-vertical")(*leaves)
     safe = np.maximum(b, 1)
     oracle = [(a * b) & 0xFF, np.where(b == 0, 0, a // safe),
               np.where(b == 0, 0, a % safe)]

@@ -1,6 +1,6 @@
-"""Device lifecycle (context scoping, auto-flush), EngineConfig, the
-backend registry contract, the deprecation shim, leaf-buffer donation and
-the shared-divider divmod lowering."""
+"""Device lifecycle (context scoping, auto-flush), EngineConfig and its
+checks, the fused evaluator table, the op surface, leaf-buffer donation
+and the shared-divider divmod lowering."""
 
 import dataclasses
 import warnings
@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 import repro.pum as pum
+from repro import backends
 from repro.core.engine import LazyArray, PulsarEngine
 from repro.kernels.fused_program import optimize_program
 
@@ -52,18 +53,19 @@ def test_device_builds_engine_from_config():
                            fuse=False, flush_threshold=7)
     dev = pum.device(cfg)
     e = dev.engine
-    assert (e.mfr, e.width, e.banks, e.use_pulsar, e.fuse,
-            e.flush_threshold) == ("H", 16, 8, False, False, 7)
+    assert e.config is cfg and dev.config is cfg
+    assert e.profile.name == "H" and e.layout.word_bits == 32
+    # the bare engine takes the same one config object
+    assert PulsarEngine(cfg).config is cfg
     # keyword overrides derive a new config
     dev2 = pum.device(cfg, width=32)
     assert dev2.config.width == 32 and dev2.config.mfr == "H"
 
 
 def test_wide_device_fuses_on_the_64bit_layout():
-    """Widths above 32 resolve to the 64-bit plane layout and FUSE (the
-    additively registered ``words-cpu-64`` evaluator), bit-exact against
-    eager — the old transparent eager fallback is gone because a fused
-    evaluator now covers the layout."""
+    """Widths above 32 resolve to the 64-bit plane layout and FUSE (on
+    ``words-cpu`` at ``word_bits=64`` on this host), bit-exact against
+    eager: every evaluator the host chooses runs the 64-bit layout."""
     dev = pum.device(width=48)
     assert dev.config.fuse and dev.layout.word_bits == 64
     a = np.array([1 << 40, 5], np.uint64)
@@ -73,35 +75,7 @@ def test_wide_device_fuses_on_the_64bit_layout():
                                   np.array([(1 << 40) // 3, 0], np.uint64))
     # widths that fit no layout word still refuse loudly
     with pytest.raises(ValueError, match="does not fit"):
-        PulsarEngine(width=48, layout=32)
-
-
-def test_device_falls_back_to_eager_without_a_layout_evaluator():
-    """When NO registered fused evaluator supports the device's layout,
-    fuse downgrades to eager with a warning (the pre-width-64 behavior,
-    now reachable only by unregistering the 64-bit evaluators)."""
-    saved = {n: pum.get_backend(n)
-             for n in ("words-cpu-64", "pallas-tpu-64", "ref-vertical-64")}
-    for n in saved:
-        pum.unregister_backend(n)
-    try:
-        with pytest.raises(LookupError, match="64-bit plane layout"):
-            pum.select_backend(require="fused", width=48, layout=64)
-        with pytest.warns(RuntimeWarning, match="runs eager"):
-            dev = pum.device(width=48)
-        assert not dev.config.fuse
-        a = np.array([1 << 40, 5], np.uint64)
-        np.testing.assert_array_equal(np.asarray(dev.asarray(a) + a),
-                                      2 * a)
-        # the direct engine path still refuses loudly
-        with pytest.raises(ValueError, match="no registered fused"):
-            PulsarEngine(width=48, fuse=True)
-    finally:
-        for n, s in saved.items():
-            pum.register_backend(
-                n, s.builder, capabilities=s.capabilities,
-                max_width=s.max_width, priority=s.priority,
-                available=s.available, layouts=s.layouts)
+        pum.EngineConfig(width=48, layout=32)
 
 
 def test_sim_backend_device_is_eager_and_bit_exact():
@@ -156,79 +130,36 @@ def test_scalar_broadcasts_share_one_leaf():
                                   np.arange(64, dtype=np.uint64) | 5)
 
 
-def test_as_device_wraps_engines_and_passes_devices_through():
-    dev = pum.device(width=16)
-    assert pum.as_device(dev) is dev
-    eng = PulsarEngine(width=16, banks=4)
-    wrapped = pum.as_device(eng)
-    assert wrapped.engine is eng and wrapped.config.banks == 4
-    # the characterization DB carries into the config: a twin derived
-    # via wrapped.config.replace(...) prices with the SAME success rates
-    assert wrapped.config.success_db is eng.db
-    twin = pum.device(wrapped.config.replace(use_pulsar=False))
-    assert twin.engine.db is eng.db
-    with pytest.raises(TypeError):
-        pum.as_device(object())
-
-
 # --------------------------------------------------------------------- #
-# Backend registry
+# Fused evaluator table
 # --------------------------------------------------------------------- #
 
 
-def test_registry_lists_builtin_backends():
-    names = pum.available_backends()
-    for n in ("fast", "sim", "words-cpu", "pallas-tpu", "ref-vertical"):
-        assert n in names
-    assert "fast" in pum.available_backends("eager")
-    assert "words-cpu" in pum.available_backends("fused")
-    assert "words-cpu" not in pum.available_backends("eager")
+def test_evaluator_table_lists_builtin_evaluators():
+    """Four evaluators, each with the plane layouts it runs; no ``-64``
+    twins: the 64-bit layout runs on the same names."""
+    table = {name: layouts
+             for name, (_, layouts) in backends.EVALUATORS.items()}
+    assert table == {"words-cpu": (32, 64), "pallas-tpu": (32, 64),
+                     "shard-words": (32,), "ref-vertical": (32, 64)}
+    assert backends.host_evaluators()[0] == "words-cpu"
+    assert "ref-vertical" not in backends.host_evaluators()
 
 
-def test_select_backend_capability_lookup():
-    # On this host the word-domain evaluator wins (Pallas needs a TPU;
-    # shard-words needs >1 device).
-    spec = pum.select_backend(require="fused", width=32, layout=32)
-    assert spec.name in ("words-cpu", "pallas-tpu", "shard-words")
-    # width 64 resolves to an evaluator declaring the 64-bit layout
-    spec64 = pum.select_backend(require="fused", width=64)
-    assert spec64.layouts == frozenset({64})
-    with pytest.raises(LookupError):
-        pum.select_backend(require="no-such-capability")
-    with pytest.raises(LookupError):  # layout filter: sharded is 32-only
-        pum.select_backend(require="sharded", layout=64)
-    with pytest.raises(KeyError, match="unknown backend"):
-        pum.get_backend("nope")
-
-
-def test_register_backend_is_additive_and_selectable():
-    """A new evaluator registers without touching engine/compiler code:
-    highest priority + available wins the capability lookup."""
-    calls = []
-
-    def builder(program, interpret=False, donate=False):
-        calls.append(program)
-        from repro.kernels.fused_program import build_words_pipeline
-        return build_words_pipeline(program, donate=donate)
-
-    pum.register_backend("test-words", builder, capabilities=("fused",),
-                         max_width=32, priority=99)
-    try:
-        dev = pum.device(width=16, fuse=True)
-        a = np.array([5, 6], np.uint64)
-        np.testing.assert_array_equal(np.asarray(dev.asarray(a) + a),
-                                      2 * a)
-        assert len(calls) == 1  # our backend built the pipeline
-        # Re-registering the name replaces the builder for FUTURE
-        # pipelines even of identical structure: the cache is keyed on
-        # the spec, so the replaced builder's pipelines can't be served.
-        pum.register_backend("test-words", builder, capabilities=("fused",),
-                             max_width=32, priority=99)
-        np.testing.assert_array_equal(np.asarray(dev.asarray(a) + a),
-                                      2 * a)
-        assert len(calls) == 2  # fresh spec -> fresh compile, no stale hit
-    finally:
-        pum.unregister_backend("test-words")
+def test_fused_evaluator_on_this_host():
+    # On this one-CPU host the word-domain evaluator runs both layouts
+    # (Pallas needs a TPU; shard-words needs >1 device).
+    for wb in (32, 64):
+        assert backends.fused_evaluator(wb) == "words-cpu"
+    assert backends.fused_evaluator(pum.LAYOUT64) == "words-cpu"
+    # a device's own choice, and a pinned one it must be able to run
+    assert pum.device(width=48).layout.word_bits == 64
+    with pytest.raises(ValueError, match="unknown fused evaluator"):
+        backends.fused_evaluator(32, pinned="nope")
+    with pytest.raises(ValueError, match="unknown fused evaluator"):
+        pum.EngineConfig(fused_backend="fast")
+    with pytest.raises(ValueError, match="64-bit plane layout"):
+        pum.EngineConfig(width=48, fused_backend="shard-words")
 
 
 @pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
@@ -260,40 +191,15 @@ def test_compile_cache_has_a_fixed_home(monkeypatch, tmp_path, from_env):
 
 
 def test_unknown_eager_backend_fails_loudly():
-    with pytest.raises(KeyError, match="unknown backend"):
+    with pytest.raises(ValueError, match="'fast' or 'sim'"):
         pum.device(backend="warp-drive", fuse=False)
-    with pytest.raises(ValueError, match="no eager dataplane"):
+    with pytest.raises(ValueError, match="'fast' or 'sim'"):
         pum.device(backend="words-cpu", fuse=False)
 
 
 # --------------------------------------------------------------------- #
-# Deprecation shim
+# Op surface
 # --------------------------------------------------------------------- #
-
-
-def test_engine_method_surface_emits_deprecation_warnings():
-    """The legacy PulsarEngine op methods survive as a compat shim: same
-    results, but each call warns toward repro.pum."""
-    e = PulsarEngine(width=16)
-    a = np.array([9, 5], np.uint64)
-    b = np.array([3, 0], np.uint64)
-    for name, args, want in [
-            ("and_", (a, b), a & b), ("or_", (a, b), a | b),
-            ("xor", (a, b), a ^ b), ("add", (a, b), a + b),
-            ("sub", (a, b), a - b), ("mul", (a, b), a * b),
-            ("div", (a, b), np.array([3, 0], np.uint64)),
-            ("mod", (a, b), np.array([0, 0], np.uint64)),
-            ("less_than", (a, b), np.zeros(2, np.uint64)),
-            ("popcount", (a,), np.array([2, 2], np.uint64)),
-            ("reduce_bits", (a, "or"), np.ones(2, np.uint64))]:
-        with pytest.warns(DeprecationWarning, match=f"PulsarEngine.{name}"):
-            got = getattr(e, name)(*args)
-        np.testing.assert_array_equal(np.asarray(got, np.uint64), want,
-                                      err_msg=name)
-    with pytest.warns(DeprecationWarning, match="PulsarEngine.divmod"):
-        q, r = e.divmod(a, np.array([2, 2], np.uint64))
-    np.testing.assert_array_equal(q, a // 2)
-    np.testing.assert_array_equal(r, a % 2)
 
 
 def test_pum_api_does_not_warn():
@@ -319,7 +225,7 @@ def test_donate_leaves_is_bit_exact():
     b = rng.integers(0, 1 << 16, n, dtype=np.uint64)
     plain = pum.device(width=16, fuse=True)
     donating = pum.device(width=16, fuse=True, donate_leaves=True)
-    assert donating.engine.donate_leaves
+    assert donating.config.donate_leaves
 
     def prog(dev):
         x, y = dev.asarray(a), dev.asarray(b)

@@ -3,7 +3,7 @@ devices under their pipeline's placement.
 
 * The size rule (``backends.shards_over_devices``): a flush whose leaves
   outgrow one chip of a multi-device host goes to ``shard-words``; every
-  other flush keeps the priority choice.
+  other flush keeps the platform's choice.
 * Residency: a cached leaf commits once, at the flush that seeds the
   cache, under the placement of the pipeline that reads it, and later
   flushes place nothing (``engine.leaf_bytes_placed``, ``flush.place``).
@@ -63,9 +63,9 @@ def _and_program(n_leaves=3):
 ])
 def test_get_pipeline_applies_the_size_rule(monkeypatch, memory,
                                             leaf_bytes, want):
-    """On this one-CPU host the priority choice is ``words-cpu``; the
-    device memory the rule reads is stood in for, not the selection."""
-    monkeypatch.setattr(fp, "device_memory", lambda: memory)
+    """On this one-CPU host the platform's choice is ``words-cpu``; the
+    device memory the rule reads is stood in for, not the choice."""
+    monkeypatch.setattr(backends, "device_memory", lambda: memory)
     fp._cached_pipeline.cache_clear()
     pipeline = fp.get_pipeline(_and_program(), leaf_bytes=leaf_bytes)
     assert hasattr(pipeline, "placement") is (want == "shard-words")

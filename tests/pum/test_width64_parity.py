@@ -1,8 +1,8 @@
 """Width-64 plane layout: operator parity across the eager, fused and
 raw-lane paths at widths 33/48/64 (div-by-zero and boundary values
-included), every registered 64-bit evaluator bit-exact against eager
+included), every evaluator bit-exact on the 64-bit layout against eager
 NumPy, the layout-keyed pipeline cache, PumArray slicing, and the
-``shard-words`` multi-device fused backend."""
+``shard-words`` multi-device fused evaluator."""
 
 import numpy as np
 import pytest
@@ -165,8 +165,8 @@ def test_raw_planewise_on_64bit_layout_is_single_lane():
     rng = np.random.default_rng(21)
     a = rng.integers(0, 1 << 64, 65, dtype=np.uint64)
     b = rng.integers(0, 1 << 64, 65, dtype=np.uint64)
-    eager = PulsarEngine(width=48)
-    fused = PulsarEngine(width=48, fuse=True)
+    eager = PulsarEngine(pum.EngineConfig(width=48, fuse=False))
+    fused = PulsarEngine(pum.EngineConfig(width=48, fuse=True))
 
     def chain(e):
         t = e._and(a, b)
@@ -186,7 +186,7 @@ def test_raw_planewise_on_64bit_layout_is_single_lane():
 def test_raw_planewise_on_32bit_layout_still_splits():
     rng = np.random.default_rng(23)
     a = rng.integers(0, 1 << 64, 33, dtype=np.uint64)
-    e = PulsarEngine(width=16, fuse=True)
+    e = PulsarEngine(pum.EngineConfig(width=16, fuse=True))
     t = e._and(a, a)
     g = e._graph
     assert g.raw and g.n == 66 and g.width == 32
@@ -210,14 +210,14 @@ def test_explicit_64bit_layout_on_narrow_width():
 
 
 # --------------------------------------------------------------------- #
-# Every registered 64-bit evaluator is bit-exact
+# Every evaluator is bit-exact on the 64-bit layout
 # --------------------------------------------------------------------- #
 
 
 def test_all_wide_evaluators_bit_exact():
-    """words-cpu-64 (NumPy word domain), ref-vertical-64 (jnp planes) and
-    pallas-tpu-64 (interpret mode off-TPU) agree with eager NumPy on the
-    same wire leaves."""
+    """words-cpu (the word domain), ref-vertical (jnp planes) and
+    pallas-tpu (interpret mode off-TPU), each at ``word_bits=64``, agree
+    with eager NumPy on the same wire leaves."""
     rng = np.random.default_rng(27)
     n = 96
     a = rng.integers(0, 1 << 64, n, dtype=np.uint64)
@@ -233,7 +233,7 @@ def test_all_wide_evaluators_bit_exact():
     t = (a + b) ^ a
     want = [t, (b < t).astype(np.uint64),
             np.array([bin(int(x)).count("1") for x in t], np.uint64)]
-    for name in ("words-cpu-64", "ref-vertical-64", "pallas-tpu-64"):
+    for name in ("words-cpu", "ref-vertical", "pallas-tpu"):
         outs = fused_program.get_pipeline(prog, backend=name,
                                           interpret=True)(*leaves)
         for w, o in zip(want, outs):
@@ -247,7 +247,7 @@ def test_wide_pipeline_rejects_narrow_only_backend():
         ops=(fused_program.FusedOp("xor", (0, 0)),), outputs=(1,),
         layout=LAYOUT64)
     with pytest.raises(ValueError, match="64-bit plane layout"):
-        fused_program.get_pipeline(prog, backend="words-cpu")
+        fused_program.get_pipeline(prog, backend="shard-words")
 
 
 # --------------------------------------------------------------------- #
@@ -341,12 +341,12 @@ def test_ref_postponing_reaches_the_auto_controller():
 
 def test_ref_postponing_validates_loudly():
     with pytest.raises(ValueError, match="JEDEC"):
-        PulsarEngine(width=16, controller="auto", ref_postponing=9)
+        pum.EngineConfig(width=16, controller="auto", ref_postponing=9)
     with pytest.raises(ValueError, match="JEDEC"):
         pum.EngineConfig(ref_postponing=0)
     # silently-inert combination is rejected, not ignored
     with pytest.raises(ValueError, match="controller='auto'"):
-        PulsarEngine(width=16, ref_postponing=4)
+        pum.EngineConfig(width=16, ref_postponing=4)
 
 
 # --------------------------------------------------------------------- #
@@ -364,7 +364,7 @@ def test_shard_words_single_device_parity():
     eager = pum.device(width=32, fuse=False)
     sharded = pum.device(width=32, fuse=True,
                          fused_backend="shard-words")
-    assert sharded.engine.fused_backend == "shard-words"
+    assert sharded.config.fused_backend == "shard-words"
 
     def run(dev):
         x = dev.asarray(a)
@@ -377,10 +377,10 @@ def test_shard_words_single_device_parity():
 
 @pytest.mark.sharded
 def test_shard_words_rejects_wide_layout():
-    with pytest.raises(ValueError, match="layouts"):
-        PulsarEngine(width=48, fuse=True, fused_backend="shard-words")
-    with pytest.raises(ValueError, match="no fused"):
-        PulsarEngine(width=16, fuse=True, fused_backend="fast")
+    with pytest.raises(ValueError, match="64-bit plane layout"):
+        pum.EngineConfig(width=48, fuse=True, fused_backend="shard-words")
+    with pytest.raises(ValueError, match="unknown fused evaluator"):
+        pum.EngineConfig(width=16, fuse=True, fused_backend="fast")
 
 
 @pytest.mark.sharded
@@ -400,9 +400,9 @@ def test_shard_words_multidevice_parity():
         import jax
         assert len(jax.devices()) == 8
         import repro.pum as pum
-        # multi-device hosts auto-select the sharded pipeline
-        assert pum.select_backend(require="fused", width=32,
-                                  layout=32).name == "shard-words"
+        # multi-device hosts choose the sharded pipeline
+        from repro.backends import fused_evaluator
+        assert fused_evaluator(32) == "shard-words"
         rng = np.random.default_rng(5)
         a = rng.integers(0, 1 << 32, 1000, dtype=np.uint64)
         b = rng.integers(0, 1 << 32, 1000, dtype=np.uint64)

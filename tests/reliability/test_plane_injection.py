@@ -345,7 +345,7 @@ def test_flush_span_reports_attempts(tmp_path):
 
 
 # --------------------------------------------------------------------- #
-# Device.calibrate / as_device plumbing
+# Device.calibrate plumbing
 
 
 def test_device_calibrate_save_and_reuse(tmp_path):
@@ -364,10 +364,3 @@ def test_calibrate_inject_on_eager_device_raises():
     with pytest.raises(ValueError, match="fuse"):
         dev.calibrate(inject=True, n_subarrays=4, n_columns=64,
                       n_patterns=4)
-
-
-def test_as_device_carries_reliability():
-    dev = fused_device()
-    dev.calibrate(n_subarrays=4, n_columns=64, n_patterns=4)
-    again = pum.as_device(dev.engine)
-    assert again.config.reliability is dev.config.reliability

@@ -6,6 +6,7 @@ from repro.core import (MFR_H, MFR_M, DramGeometry, PulsarChip,
                         PulsarEngine, PulsarExecutor)
 from repro.core.alu import BitSerialAlu
 from repro.core.charact import default_db
+from repro.pum import EngineConfig
 
 
 def test_public_api_surface():
@@ -29,8 +30,10 @@ def test_paper_headline_claims_hold_in_sim():
         assert x.max_n_rg() == max_rows
     db = default_db()
     assert db.mean("H", 3, 32) > db.mean("H", 3, 4) + 0.1
-    pulsar = PulsarEngine(mfr="M", use_pulsar=True)
-    frac = PulsarEngine(mfr="M", use_pulsar=False)
+    pulsar = PulsarEngine(EngineConfig(mfr="M", use_pulsar=True,
+                                       fuse=False))
+    frac = PulsarEngine(EngineConfig(mfr="M", use_pulsar=False,
+                                     fuse=False))
     for kind, planes in (("reduce_and", 64), ("reduce_xor", 64),
                          ("add", None), ("mul", None), ("div", None)):
         _, _, sr_p, c_p = pulsar._cfg_for(kind, 32, planes)

@@ -3,9 +3,10 @@
 
 The ``repro.pum`` surface is the repo's one stable contract: this script
 compares the *actual* exports (module ``__all__`` + the public attribute
-surface of ``PumArray``/``Device``/``EngineConfig`` + the built-in backend
-registrations) against the frozen snapshot below and exits 1 on any
-drift — an accidentally-added export fails CI just like a removed one.
+surface of ``PumArray``/``Device``/``EngineConfig`` + the fused
+evaluators and eager dataplanes of ``repro.backends``) against the frozen
+snapshot below and exits 1 on any drift — an accidentally-added export
+fails CI just like a removed one.
 
 Intentional surface changes update ``EXPECTED`` here (run with
 ``--print`` to emit the current surface) and ``docs/api.md`` together.
@@ -21,14 +22,13 @@ import sys
 # operator set IS the API.
 EXPECTED = {
     "repro.pum": [
-        "BackendSpec", "CapturedProgram", "CounterBank", "Device",
+        "CapturedProgram", "CounterBank", "Device",
         "EngineConfig", "EngineStats", "FlushHandle", "LAYOUT32",
         "LAYOUT64", "PlaneLayout", "PumArray",
         "ReliabilityConfig", "ReliabilityMap", "Tracer",
         "TunedPlan", "Tuner", "WorkloadProfile",
-        "as_device", "asarray", "available_backends", "calibrate",
-        "default_device", "device", "get_backend", "get_layout", "profile",
-        "register_backend", "select_backend", "unregister_backend",
+        "asarray", "calibrate", "default_device", "device", "get_layout",
+        "profile",
     ],
     "PumArray": [
         "__add__", "__and__", "__array__", "__array_priority__",
@@ -44,8 +44,9 @@ EXPECTED = {
     "Device": [
         "__enter__", "__exit__", "__init__", "__repr__", "asarray",
         "autotune", "calibrate", "capture", "charge", "client", "close",
-        "counters", "flush", "flush_async", "latency_ms", "layout",
-        "reliability", "reset_counters", "reset_stats", "stats", "width",
+        "config", "counters", "flush", "flush_async", "latency_ms",
+        "layout", "reliability", "reset_counters", "reset_stats", "stats",
+        "width",
     ],
     "EngineConfig": [
         "backend", "banks", "chained", "cmd_buffer_lookahead",
@@ -55,11 +56,10 @@ EXPECTED = {
         "ref_postponing", "reliability", "row_bits",
         "seed", "success_db", "use_pulsar", "width",
     ],
-    # Built-in registrations (a superset is allowed: registering more
-    # backends is the designed extension point).
-    "backends": ["fast", "pallas-tpu", "pallas-tpu-64", "ref-vertical",
-                 "ref-vertical-64", "shard-words", "sim", "words-cpu",
-                 "words-cpu-64"],
+    # The fused evaluators (each runs the 64-bit layout under its own
+    # name where it runs it at all) and the eager dataplanes.
+    "backends": ["fast", "pallas-tpu", "ref-vertical", "shard-words",
+                 "sim", "words-cpu"],
 }
 
 _SKIP = {"__module__", "__qualname__", "__doc__", "__slots__", "__dict__",
@@ -80,6 +80,7 @@ def _class_surface(cls) -> list[str]:
 
 def actual_surface() -> dict[str, list[str]]:
     import repro.pum as pum
+    from repro.backends import DATAPLANES, EVALUATORS
 
     missing = [n for n in pum.__all__ if not hasattr(pum, n)]
     if missing:
@@ -98,7 +99,7 @@ def actual_surface() -> dict[str, list[str]]:
         "EngineConfig": sorted(
             f.name for f in
             __import__("dataclasses").fields(pum.EngineConfig)),
-        "backends": sorted(pum.available_backends()),
+        "backends": sorted((*EVALUATORS, *DATAPLANES)),
     }
 
 
@@ -112,11 +113,6 @@ def main(argv: list[str] | None = None) -> int:
     failures = []
     for key, want in EXPECTED.items():
         have = got[key]
-        if key == "backends":
-            lost = sorted(set(want) - set(have))
-            if lost:
-                failures.append(f"{key}: built-in backends missing: {lost}")
-            continue
         if sorted(want) != have:
             extra = sorted(set(have) - set(want))
             lost = sorted(set(want) - set(have))
